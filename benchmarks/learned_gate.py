@@ -32,7 +32,8 @@ import argparse
 import os
 import time
 
-from benchmarks.common import bench_timing, write_csv, write_json
+from benchmarks.common import (bench_timing, use_compile_cache, write_csv,
+                               write_json)
 from benchmarks.structure_sweep import check_topology, make_spec
 from repro.learn import LearnConfig
 from repro.scenarios import learned_summary, sweep_structure, trend_summary
@@ -140,6 +141,7 @@ def main() -> None:
     ap.add_argument("--out", type=str, default=None,
                     help=f"output JSON path (default {BENCH_JSON})")
     args = ap.parse_args()
+    use_compile_cache()
     run(tiny=args.tiny, steps=args.steps,
         instances_per_cell=args.instances, out=args.out, seed=args.seed,
         devices=args.devices, processes=args.processes)

@@ -16,6 +16,7 @@ from benchmarks import (fig4_makespan, fig5_stretch, fig6_regions,
                         fig7_carbon_vs_energy, learned_gate,
                         online_vs_offline, stream_serve, structure_sweep,
                         table1a_servers, table1b_tasks)
+from benchmarks.common import use_compile_cache
 
 BENCHES = {
     "fig4": fig4_makespan.run,
@@ -37,6 +38,7 @@ def main() -> int:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset, e.g. fig5,table1a")
     args = ap.parse_args()
+    use_compile_cache()
     names = (args.only.split(",") if args.only else list(BENCHES))
 
     t0 = time.time()

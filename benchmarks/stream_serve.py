@@ -41,7 +41,8 @@ import time
 
 import numpy as np
 
-from benchmarks.common import bench_timing, write_csv, write_json
+from benchmarks.common import (bench_timing, use_compile_cache, write_csv,
+                               write_json)
 from repro.core.instance import Instance, pack
 from repro.core.objectives import makespan
 from repro.core.solvers.online_jax import online_greedy_jax
@@ -271,6 +272,7 @@ def main() -> None:
                     help="skip the grid; stream one tiny traced cell and "
                          "export its Chrome-trace JSON to PATH")
     args = ap.parse_args()
+    use_compile_cache()
     if args.trace_out:
         export_trace(args.trace_out, seed=args.seed)
         return

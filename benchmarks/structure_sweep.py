@@ -38,7 +38,8 @@ import argparse
 import os
 import time
 
-from benchmarks.common import bench_timing, write_csv, write_json
+from benchmarks.common import (bench_timing, use_compile_cache, write_csv,
+                               write_json)
 from repro.core.solvers.annealing import SAConfig
 from repro.scenarios import (SweepSpec, structure_cells, sweep_structure,
                              trend_summary)
@@ -204,6 +205,7 @@ def main() -> None:
     ap.add_argument("--out", type=str, default=None,
                     help=f"output JSON path (default {BENCH_JSON})")
     args = ap.parse_args()
+    use_compile_cache()
     run(tiny=args.tiny, offline=not args.no_offline,
         instances_per_cell=args.instances, out=args.out, seed=args.seed,
         devices=args.devices, processes=args.processes)

@@ -11,21 +11,16 @@ tiers) but are grounded in v5e slices: speed scales with chip count times
 a utilization factor (small slices run at higher MFU — less collective
 overhead — exactly the speed/efficiency tension §3.2 of the paper probes).
 
-If a dry-run JSON for the (arch, shape) cell exists the step time comes
-from its roofline terms; otherwise from the analytic 6·N·D estimate.
+Step time comes from the analytic 6·N·D estimate, so results depend only
+on committed code.
 """
 from __future__ import annotations
 
 import dataclasses
-import glob
-import json
-import os
 
+from repro.launch.roofline import PEAK_FLOPS
 from repro.models.common import ArchConfig, SHAPES
 
-PEAK_FLOPS = 197e12           # bf16 / chip
-HBM_BW = 819e9                # bytes/s / chip
-LINK_BW = 50e9                # bytes/s / link
 CHIP_POWER_KW = 0.30          # v5e chip + share of host/interconnect
 
 
@@ -54,30 +49,8 @@ TPU_V5E_CLASSES: tuple[MachineClass, ...] = (
     MachineClass("v5e-160", 160, 0.38),
 )
 
-_DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                           "experiments", "dryrun")
-
-
-def _dryrun_step_flops(arch: str, shape: str) -> float | None:
-    """Per-chip FLOPs x 256 chips from the single-pod dry-run, if present."""
-    path = os.path.join(_DRYRUN_DIR, f"{arch}__{shape}__pod16x16.json")
-    if not os.path.exists(path):
-        return None
-    try:
-        with open(path) as f:
-            rec = json.load(f)
-        if rec.get("status") != "ok" or "flops" not in rec:
-            return None
-        return float(rec["flops"]) * 256
-    except Exception:
-        return None
-
-
 def step_flops(cfg: ArchConfig, shape: str) -> float:
-    """Total FLOPs of one step of the (arch, shape) cell."""
-    measured = _dryrun_step_flops(cfg.name, shape)
-    if measured is not None:
-        return measured
+    """Total FLOPs of one step of the (arch, shape) cell (analytic)."""
     sc = SHAPES[shape]
     tokens = sc.batch * (sc.seq if sc.kind != "decode" else 1)
     n = cfg.active_param_count()

@@ -101,11 +101,57 @@ def sgs(inst: PackedInstance, prio: jnp.ndarray,
     return DecodedSchedule(start, aout, seq)
 
 
+def sweep_table(inst: PackedInstance, cum: jnp.ndarray) -> tuple | None:
+    """Every start-cost row :func:`timing_sweep` can ask for, as three
+    bf16 parts whose f32 sum is the row exactly — or ``None``, where the
+    sweep gathers each row itself.
+
+    Row ``t*M + m`` is ``cum[min(s + dur[t, m], H)] - cum[s]`` for
+    ``s = 0..H``: the emissions of task ``t`` on machine ``m`` started at
+    ``s``, per unit power.  The sweep gathers that row per step and
+    candidate, which a TPU runs element by element: the bound's time then
+    grows with instances x population x horizon.  With a table the sweep
+    picks the row with a one-hot product instead (:func:`_select_row`),
+    which runs on the MXU.  Off a TPU the gather is the cheap form, and
+    no table is built.
+
+    An f32 ``x`` is exactly ``b1 + b2 + b3`` with ``b1 = bf16(x)``,
+    ``b2 = bf16(x - b1)``, ``b3 = x - b1 - b2`` (eight significand bits
+    each), so both forms give the same row bit for bit.
+    """
+    if jax.default_backend() != "tpu":
+        return None
+    if cum.dtype != jnp.float32:
+        raise TypeError(f"sweep_table splits f32 rows exactly; cum is "
+                        f"{cum.dtype}")
+    T, M = inst.T, inst.M
+    H = cum.shape[0] - 1
+    svec = jnp.arange(H + 1, dtype=jnp.int32)
+    end = jnp.minimum(svec + inst.dur[:, :, None], H)        # [T, M, H+1]
+    rows = (cum[end] - cum[svec]).reshape(T * M, H + 1)
+    b1 = rows.astype(jnp.bfloat16)
+    r1 = rows - b1.astype(jnp.float32)
+    b2 = r1.astype(jnp.bfloat16)
+    b3 = (r1 - b2.astype(jnp.float32)).astype(jnp.bfloat16)
+    return b1, b2, b3
+
+
+def _select_row(table: tuple, j: jnp.ndarray) -> jnp.ndarray:
+    """Row ``j`` of a :func:`sweep_table`, bit-exact: each one-hot product
+    returns one bf16 part exactly in f32, and the partial sums of the
+    parts are exact."""
+    onehot = (jnp.arange(table[0].shape[0]) == j).astype(jnp.bfloat16)
+    b1, b2, b3 = (jnp.dot(onehot, b, preferred_element_type=jnp.float32)
+                  for b in table)
+    return (b1 + b2) + b3
+
+
 @functools.partial(jax.jit, static_argnames=("sweeps",))
 def timing_sweep(inst: PackedInstance, start: jnp.ndarray,
                  assign: jnp.ndarray, cum: jnp.ndarray,
                  deadline: jnp.ndarray, sweeps: int = 2,
-                 frozen: jnp.ndarray | None = None) -> jnp.ndarray:
+                 frozen: jnp.ndarray | None = None,
+                 table: tuple | None = None) -> jnp.ndarray:
     """Carbon-greedy timing pass.
 
     Keeps sequencing (per-machine order and DAG order) fixed and pushes each
@@ -123,14 +169,19 @@ def timing_sweep(inst: PackedInstance, start: jnp.ndarray,
     With fixed sequences this is coordinate descent on the separable
     start-time-cost problem — cheap, monotone (never increases carbon), and
     exact in the common case of a task whose window covers a clean valley.
+
+    ``table`` is :func:`sweep_table` of ``(inst, cum)``, built here when
+    not given; a solver that sweeps many candidates of one instance builds
+    it once and passes it (XLA leaves it inside the search loop).
     """
-    T = inst.T
+    T, M = inst.T, inst.M
     H = cum.shape[0] - 1
     d = task_durations(inst, assign)
     real = inst.task_mask
     sweepable = real if frozen is None else real & ~frozen
     svec = jnp.arange(H + 1, dtype=jnp.int32)
-    # cost_at[t, s] lookup pieces: delta(s; d) = cum[s+d] - cum[s].
+    if table is None:
+        table = sweep_table(inst, cum)
     same_m = (assign[:, None] == assign[None, :]) & real[None, :]
     succ = inst.pred.T & real[None, :]          # succ[t, v]: t -> v edge
 
@@ -147,7 +198,10 @@ def timing_sweep(inst: PackedInstance, start: jnp.ndarray,
             hi = jnp.minimum(jnp.minimum(succ_cap, mnext_cap),
                              deadline.astype(jnp.int32)) - dt
             lo = start_cur[t]
-            cost = cum[jnp.minimum(svec + dt, H)] - cum[svec]
+            if table is None:
+                cost = cum[jnp.minimum(svec + dt, H)] - cum[svec]
+            else:
+                cost = _select_row(table, t * M + assign[t])
             cost = jnp.where((svec >= lo) & (svec <= hi), cost, jnp.inf)
             s_star = jnp.argmin(cost).astype(jnp.int32)
             movable = sweepable[t] & (hi >= lo)

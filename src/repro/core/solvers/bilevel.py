@@ -22,6 +22,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.decoder import sweep_table
 from repro.core.instance import PackedInstance
 from repro.core.solvers import common
 from repro.core.solvers.annealing import SAConfig, solve_sa
@@ -80,10 +81,11 @@ def solve_bilevel(inst: PackedInstance, cum: jnp.ndarray, key: jax.Array,
                machine_rule="fixed", cfg=cfg2,
                prio_init=-baseline.start.astype(jnp.float32),
                assign_init=baseline.assign, use_kernels=use_kernels)
+    table = sweep_table(inst, cum)
     optimized = common.decode_full(
         inst, cum, deadline, p2.prio, p2.assign,
         objective=objective, machine_rule="fixed", sweeps=max(
-            getattr(cfg2, "sweeps", 2), 1))
+            getattr(cfg2, "sweeps", 2), 1), table=table)
 
     # Guard: if phase 2 somehow ended worse (it cannot, given the warm start
     # chain is kept, but belt-and-braces), fall back to the timing-swept
@@ -91,7 +93,7 @@ def solve_bilevel(inst: PackedInstance, cum: jnp.ndarray, key: jax.Array,
     fallback = common.decode_full(
         inst, cum, deadline, -baseline.start.astype(jnp.float32),
         baseline.assign, objective=objective, machine_rule="fixed",
-        sweeps=max(getattr(cfg2, "sweeps", 2), 1))
+        sweeps=max(getattr(cfg2, "sweeps", 2), 1), table=table)
     key_obj = {"carbon": 4, "energy": 3}[objective]
     use_fb = (optimized[key_obj] > fallback[key_obj]) | \
         (optimized.makespan > deadline)

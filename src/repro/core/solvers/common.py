@@ -30,7 +30,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.decoder import sgs, timing_sweep
+from repro.core.decoder import sgs, sweep_table, timing_sweep
 from repro.core.instance import PackedInstance
 from repro.core.objectives import Objectives, energy, evaluate, utilization
 from repro.core.validate import total_violations
@@ -56,19 +56,21 @@ def decode_full(inst: PackedInstance, cum: jnp.ndarray, deadline: jnp.ndarray,
                 prio: jnp.ndarray, assign: jnp.ndarray,
                 objective: str = "carbon", machine_rule: str = "fixed",
                 sweeps: int = 2,
-                frozen: jnp.ndarray | None = None) -> ScheduleResult:
+                frozen: jnp.ndarray | None = None,
+                table: tuple | None = None) -> ScheduleResult:
     """Candidate -> feasible schedule + objective values.
 
     ``frozen`` (optional bool [T]) marks already-executing tasks the timing
     sweep must not move (rolling replans); SGS placement of frozen tasks is
     pinned upstream via the instance transform + priority band (see
-    :mod:`repro.core.solvers.rolling`).
+    :mod:`repro.core.solvers.rolling`).  ``table`` is the instance's
+    :func:`repro.core.decoder.sweep_table`, built here when not given.
     """
     dec = sgs(inst, prio, assign, machine_rule=machine_rule)
     start = dec.start
     if objective != "makespan" and sweeps > 0:
         start = timing_sweep(inst, start, dec.assign, cum, deadline, sweeps,
-                             frozen=frozen)
+                             frozen=frozen, table=table)
     obj: Objectives = evaluate(inst, start, dec.assign, cum)
     return ScheduleResult(start, dec.assign, obj.makespan, obj.energy,
                           obj.carbon, utilization(inst, start, dec.assign))
@@ -99,10 +101,11 @@ def fitness_of(inst: PackedInstance, res: ScheduleResult,
 def fitness_fn(inst: PackedInstance, cum: jnp.ndarray, deadline: jnp.ndarray,
                prio: jnp.ndarray, assign: jnp.ndarray, objective: str,
                machine_rule: str, sweeps: int,
-               frozen: jnp.ndarray | None = None) -> jnp.ndarray:
+               frozen: jnp.ndarray | None = None,
+               table: tuple | None = None) -> jnp.ndarray:
     res = decode_full(inst, cum, deadline, prio, assign,
                       objective=objective, machine_rule=machine_rule,
-                      sweeps=sweeps, frozen=frozen)
+                      sweeps=sweeps, frozen=frozen, table=table)
     return fitness_of(inst, res, deadline, objective)
 
 
@@ -111,7 +114,8 @@ def population_fitness(inst: PackedInstance, cum: jnp.ndarray,
                        assign: jnp.ndarray, objective: str,
                        machine_rule: str, sweeps: int,
                        frozen: jnp.ndarray | None = None,
-                       use_kernels: bool | None = None) -> jnp.ndarray:
+                       use_kernels: bool | None = None,
+                       table: tuple | None = None) -> jnp.ndarray:
     """Fitness of a whole candidate population.  prio/assign [Pop, T] -> [Pop].
 
     The SA/GA hot loop: every proposal evaluation, init evaluation and
@@ -128,18 +132,22 @@ def population_fitness(inst: PackedInstance, cum: jnp.ndarray,
       separate gather chains.
 
     The makespan objective never touches the trace, so it always takes
-    the jnp path.  Meant to be called from inside the solvers' jitted
+    the jnp path.  Both paths sweep with one ``table``
+    (:func:`repro.core.decoder.sweep_table`), which solvers build once
+    per solve.  Meant to be called from inside the solvers' jitted
     scope with ``use_kernels`` static (the branch resolves at trace time;
     NB flipping ``REPRO_KERNELS`` after a solver cached its trace has no
     effect on that cache — pass the argument in tests).
     """
+    if objective != "makespan" and sweeps > 0 and table is None:
+        table = sweep_table(inst, cum)
     if objective != "makespan" and ops.kernels_enabled(use_kernels):
         def _decode(p, a):
             dec = sgs(inst, p, a, machine_rule=machine_rule)
             start = dec.start
             if sweeps > 0:
                 start = timing_sweep(inst, start, dec.assign, cum, deadline,
-                                     sweeps, frozen=frozen)
+                                     sweeps, frozen=frozen, table=table)
             return start, dec.assign
 
         starts, assigns = jax.vmap(_decode)(prio, assign)
@@ -155,7 +163,7 @@ def population_fitness(inst: PackedInstance, cum: jnp.ndarray,
         raise ValueError(f"unknown objective {objective!r}")
     return jax.vmap(lambda p, a: fitness_fn(
         inst, cum, deadline, p, a, objective, machine_rule, sweeps,
-        frozen=frozen))(prio, assign)
+        frozen=frozen, table=table))(prio, assign)
 
 
 def random_allowed_assign(key: jax.Array, inst: PackedInstance,

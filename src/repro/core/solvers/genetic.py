@@ -12,7 +12,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.decoder import upward_rank
+from repro.core.decoder import sweep_table, upward_rank
 from repro.core.instance import PackedInstance
 from repro.core.solvers import common
 from repro.core.solvers.annealing import SolveOut
@@ -48,9 +48,10 @@ def solve_ga(inst: PackedInstance, cum: jnp.ndarray, deadline: jnp.ndarray,
     # and mutations are masked, and crossover mixes identical frozen genes.
     free = (jnp.ones((T,), bool) if frozen is None else ~frozen)
     sweeps = 0 if objective == "makespan" else cfg.sweeps
+    table = sweep_table(inst, cum) if sweeps else None
     fit_v = lambda p, a: common.population_fitness(  # noqa: E731
         inst, cum, deadline, p, a, objective, machine_rule, sweeps,
-        frozen=frozen, use_kernels=use_kernels)
+        frozen=frozen, use_kernels=use_kernels, table=table)
 
     k_init, k_assign, k_run = jax.random.split(key, 3)
     base = upward_rank(inst) if prio_init is None else prio_init

@@ -14,10 +14,10 @@ No sort: the interpolated quantile needs only *two order statistics* per
 window (``floor(theta * (n-1))`` and its successor), so the kernel selects
 them by stable rank counting —
 
-    rank[w] = #{u : x[u] < x[w]}  +  #{u < w : x[u] == x[w]}
+    rank[w] = #{u : x[u] < x[w]}  +  #{u before w : x[u] == x[w]}
 
-— an O(W^2) compare-and-count per window that is pure VPU work (W <= 128
-lanes), needs no sort network, and *selects* values rather than computing
+— an O(W^2) compare-and-count per window that is pure VPU work, needs no
+sort network, and *selects* values rather than computing
 with them.  Selection makes the bit-exactness contract provable: the
 chosen order statistics are bitwise the values ``jnp.sort`` would place at
 those positions (stable ranks are a permutation; ties share one value).
@@ -31,9 +31,15 @@ decisions) and kernel == jnp path bit-for-bit — the contract
 the kernel came out one ulp off on some windows: the Pallas interpreter
 and the jnp graph made different mul+add contraction choices.)
 
-Windows are shifted slices of the horizon, so each epoch block loads one
-``[be + W]`` stretch of the VMEM-resident trace and builds its ``[be, W]``
-window block from static sub-slices — no gathers anywhere.
+Layout: epochs run along the 128 lanes, window slots along the sublanes.
+The trace is held lane-dense (``[rows, 128]``) and VMEM-resident; a grid
+step takes one row of 128 epochs, loads that row and the next (the
+windows reach past it), and builds its ``[W, 128]`` window block with one
+strided lane rotation (``pltpu.roll``): sublane ``k`` is the two rows
+rotated left by ``W - 1 - k``, so it holds slot ``w = W - 1 - k`` of
+every epoch's window.  The rank count then compares whole vregs, the
+selections reduce over sublanes, and the traced window length arrives
+as a scalar in SMEM.  No gather, no sort, no 1-D dynamic slice.
 """
 from __future__ import annotations
 
@@ -42,60 +48,68 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
+SUBLANE = 8
 
 
-def _kernel(int_ref, theta_ref, win_ref, a_ref, b_ref, n_ref, *,
-            n_epochs: int, max_window: int, block_epochs: int, w_pad: int):
-    """One epoch block: intensity (full, padded) -> (a, b, n) [be] each.
+def _kernel(win_ref, int_ref, theta_ref, a_ref, b_ref, n_ref, *,
+            n_epochs: int, max_window: int, w_rows: int, x_rows: int):
+    """One block of 128 epochs -> (a, b, n), each [1, 128].
 
-    int_ref: [Ipad] f32; theta_ref: [be] f32; win_ref: [1] i32 (the traced
-    window length); a/b: the ``floor(theta*(n-1))``-th and successor order
-    statistics of each window; n: its valid count.
+    win_ref: [1, 1] i32 in SMEM (the traced window length); int_ref: the
+    whole trace, [rows, 128] f32; theta_ref: [1, 128] f32.  a/b are the
+    ``floor(theta*(n-1))``-th and successor order statistics of each
+    epoch's window; n is its valid count.
     """
-    be = block_epochs
-    t0 = pl.multiple_of(pl.program_id(0) * be, be)
-    window = win_ref[0]
-
-    # Window block [be, Wp]: row i = intensity[t0+i : t0+i+Wp] — static
-    # sub-slices of one VMEM-resident trace, shifted by one per row.
-    win = jnp.stack([int_ref[pl.ds(t0 + i, w_pad)] for i in range(be)])
-    off = jax.lax.broadcasted_iota(jnp.int32, (be, w_pad), 1)
-    epoch = jax.lax.broadcasted_iota(jnp.int32, (be, w_pad), 0) + t0
-    valid = (off < window) & (off < max_window) & (epoch + off < n_epochs)
-    win = jnp.where(valid, win, jnp.inf)          # invalid slots sort last
-    n = jnp.sum(valid.astype(jnp.int32), axis=1)  # [be]
+    p = pl.program_id(0)
+    window = win_ref[0, 0]
+    rows = int_ref[pl.ds(p, x_rows), :]                 # [x_rows, 128]
+    x = jnp.concatenate([rows[r:r + 1] for r in range(x_rows)], axis=1)
+    span = x_rows * LANE
+    # Sublane k <- x rotated left by w_rows-1-k: win[k, j] = trace[t + w],
+    # slot w = w_rows-1-k of epoch t = 128p + j.
+    win = pltpu.roll(jnp.broadcast_to(x, (w_rows, span)),
+                     (span - (w_rows - 1)) % span, 1,
+                     stride=1, stride_axis=0)[:, :LANE]
+    k = jax.lax.broadcasted_iota(jnp.int32, (w_rows, LANE), 0)
+    w = (w_rows - 1) - k
+    t = p * LANE + jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
+    valid = (w < window) & (w < max_window) & (t + w < n_epochs)
+    win = jnp.where(valid, win, jnp.inf)                # invalid slots last
+    # The valid count, in closed form: #{w < max_window : w < window,
+    # t + w < n_epochs}.
+    n = jnp.maximum(jnp.minimum(jnp.minimum(window, max_window),
+                                n_epochs - t), 0)       # [1, 128]
 
     # Selection indices — the exact index arithmetic of quantile_threshold
     # (vi is one multiply and floor is exact, so lo_i/hi_i are bitwise the
     # indices the jnp path gathers at; the *lerp* happens in the wrapper).
-    vi = theta_ref[...].astype(jnp.float32) * (n - 1).astype(jnp.float32)
+    vi = theta_ref[...] * (n - 1).astype(jnp.float32)
     lo_i = jnp.floor(vi).astype(jnp.int32)
     hi_i = jnp.minimum(lo_i + 1, n - 1)
 
-    # Stable rank of every slot; valid slots get a permutation of 0..n-1
-    # (ties broken by position), +inf slots rank >= n — never selected.
-    x_w = win[:, :, None]                          # [be, Wp(w), 1]
-    x_u = win[:, None, :]                          # [be, 1, Wp(u)]
-    before = (jax.lax.broadcasted_iota(jnp.int32, (w_pad, w_pad), 1)
-              < jax.lax.broadcasted_iota(jnp.int32, (w_pad, w_pad), 0))
-    rank = (jnp.sum((x_u < x_w).astype(jnp.int32), axis=2)
-            + jnp.sum(((x_u == x_w) & before[None]).astype(jnp.int32),
-                      axis=2))                     # [be, Wp]
+    # Stable rank of every slot: valid slots get a permutation of 0..n-1
+    # (ties broken by sublane), +inf slots rank >= n — never selected.
+    rank = jnp.zeros((w_rows, LANE), jnp.int32)
+    for u in range(w_rows):
+        x_u = win[u:u + 1, :]
+        rank += ((x_u < win) | ((x_u == win) & (u < k))).astype(jnp.int32)
 
     # Select the two order statistics (exactly one slot matches each rank;
     # summing the zeros is the identity, so the selection is exact).
-    a_ref[...] = jnp.sum(jnp.where(rank == lo_i[:, None], win, 0.0), axis=1)
-    b_ref[...] = jnp.sum(jnp.where(rank == hi_i[:, None], win, 0.0), axis=1)
+    a_ref[...] = jnp.sum(jnp.where(rank == lo_i, win, 0.0), axis=0,
+                         keepdims=True)
+    b_ref[...] = jnp.sum(jnp.where(rank == hi_i, win, 0.0), axis=0,
+                         keepdims=True)
     n_ref[...] = n
 
 
-@functools.partial(jax.jit, static_argnames=("max_window", "block_epochs",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("max_window", "interpret"))
 def gate_quantile_stats_pallas(intensity: jnp.ndarray, theta: jnp.ndarray,
                                window: jnp.ndarray, *, max_window: int,
-                               interpret: bool, block_epochs: int = 8
+                               interpret: bool
                                ) -> tuple[jnp.ndarray, jnp.ndarray,
                                           jnp.ndarray]:
     """intensity [E] f32; theta [E] f32 (per-epoch — broadcast a scalar
@@ -114,29 +128,32 @@ def gate_quantile_stats_pallas(intensity: jnp.ndarray, theta: jnp.ndarray,
     windows; they are sliced off before returning.
     """
     E = intensity.shape[0]
-    be = block_epochs
-    Ep = -(-E // be) * be
-    Wp = -(-max_window // LANE) * LANE
-    Ipad = -(-(Ep + Wp) // LANE) * LANE
+    Rp = -(-E // LANE)                       # epoch rows, one per grid step
+    w_rows = -(-max_window // SUBLANE) * SUBLANE
+    x_rows = 1 + -(-(w_rows - 1) // LANE)    # trace rows one block reads
+    Rt = -(-(Rp + x_rows - 1) // SUBLANE) * SUBLANE
 
-    intp = jnp.pad(intensity.astype(jnp.float32), (0, Ipad - E))
-    thetap = jnp.pad(theta.astype(jnp.float32), (0, Ep - E))
-    win1 = jnp.reshape(window.astype(jnp.int32), (1,))
+    intp = jnp.pad(intensity.astype(jnp.float32),
+                   (0, Rt * LANE - E)).reshape(Rt, LANE)
+    thetap = jnp.pad(theta.astype(jnp.float32),
+                     (0, Rp * LANE - E)).reshape(Rp, 1, LANE)
+    win1 = jnp.reshape(window.astype(jnp.int32), (1, 1))
 
     kernel = functools.partial(_kernel, n_epochs=E, max_window=max_window,
-                               block_epochs=be, w_pad=Wp)
+                               w_rows=w_rows, x_rows=x_rows)
+    row_spec = pl.BlockSpec((None, 1, LANE), lambda p: (p, 0, 0))
     a, b, n = pl.pallas_call(
         kernel,
-        grid=(Ep // be,),
+        grid=(Rp,),
         in_specs=[
-            pl.BlockSpec((Ipad,), lambda p: (0,)),
-            pl.BlockSpec((be,), lambda p: (p,)),
-            pl.BlockSpec((1,), lambda p: (0,)),
+            pl.BlockSpec((1, 1), lambda p: (0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((Rt, LANE), lambda p: (0, 0)),
+            row_spec,
         ],
-        out_specs=[pl.BlockSpec((be,), lambda p: (p,))] * 3,
-        out_shape=[jax.ShapeDtypeStruct((Ep,), jnp.float32),
-                   jax.ShapeDtypeStruct((Ep,), jnp.float32),
-                   jax.ShapeDtypeStruct((Ep,), jnp.int32)],
+        out_specs=[row_spec] * 3,
+        out_shape=[jax.ShapeDtypeStruct((Rp, 1, LANE), jnp.float32),
+                   jax.ShapeDtypeStruct((Rp, 1, LANE), jnp.float32),
+                   jax.ShapeDtypeStruct((Rp, 1, LANE), jnp.int32)],
         interpret=interpret,
-    )(intp, thetap, win1)
-    return a[:E], b[:E], n[:E]
+    )(win1, intp, thetap)
+    return tuple(o.reshape(Rp * LANE)[:E] for o in (a, b, n))

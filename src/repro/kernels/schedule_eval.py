@@ -4,34 +4,33 @@ The paper's solver hot spot after vectorization is *population fitness*:
 for thousands of candidate schedules per instance, integrate each task's
 emissions over the carbon trace (Def. 2.3).  With the cumulative-trace
 trick each task costs ``P * (cum[s+d] - cum[s])`` — two gathers.  TPUs
-hate scalar gathers but love matmuls, so the kernel turns the per-tile
-gather into a one-hot x trace product on the MXU/VPU:
+have no vector gather, so the kernel turns each gather into a walk over
+the horizon that *selects*:
 
-    delta[p, t] = sum_h cum[h] * (onehot(e1) - onehot(e0))[p, t, h]
+    c1[i] = cum[h]  where e1[i] == h      (for h = 0 .. H)
+    c0[i] = cum[h]  where e0[i] == h
 
-Tiling: grid over population blocks (``bp`` candidates) x task blocks
-(``bt`` tasks, lane-aligned); the horizon axis H lives fully in VMEM
-(a year of 15-min epochs = 35k floats = 137 KiB — trivially resident).
-The [bp*bt, H] one-hot is never materialized — a ``fori_loop`` walks H in
-128-wide slabs, comparing a broadcasted iota against e0/e1 and
-accumulating, keeping the working set at ``bp*bt*128`` floats.  (An
-earlier revision unrolled that walk as a Python loop: a year-long trace
-unrolled ~274 einsums into the kernel body and blew up compile time; the
-``fori_loop`` emits one body regardless of horizon.)
+Layout: the candidates' task slots are flattened and laid out lane-dense,
+``[rows, 128]`` (one slot per lane), and tiled ``block_rows`` rows at a
+time.  ``cum`` sits in SMEM in ``block_h``-epoch blocks along a second,
+innermost grid axis, so each step reads ``cum[h]`` as a scalar and does
+one vector compare-and-select per slot vreg — no gather, no cross-lane
+reduction, and no one-hot matrix in memory.  The two selections carry
+across horizon blocks in VMEM scratch; the last block writes
+``c1 - c0``.  Any horizon fits: a year of 15-minute epochs (35k floats)
+is 35 SMEM blocks of 1024 (the 1-D f32 tile, so a block is one tile).
 
 Bit-exactness (the contract ``repro.kernels.ops.population_carbon`` is
-property-tested under): the kernel returns the per-task trace deltas
-``cum[e1] - cum[e0]`` and leaves the masked, power-weighted reduction to
-the wrapper, which uses the *same expression* as
-:func:`repro.core.objectives.carbon`.  Each delta is exact — every slab
-product has at most two nonzero terms (+cum[e1], -cum[e0]; IEEE addition
-of zeros is the identity and addition is commutative, so the slab
-accumulation reproduces a single f32 subtract bit-for-bit) — so the
-kernel path equals the jnp gather path bitwise, not just allclose.
-Start/end epochs are clamped into ``[0, H]`` exactly as the jnp oracle
-clips them; candidates overrunning the trace (routine for infeasible SA
-proposals before the penalty prices them) integrate to the trace edge
-instead of reading zero padding.
+property-tested under): every epoch in ``[0, H]`` is visited exactly once
+and each clamped end epoch matches exactly one of them, so ``c1`` and
+``c0`` *are* ``cum[e1]`` and ``cum[e0]`` — no arithmetic touches them —
+and the one subtraction is the jnp path's ``cum[e1] - cum[e0]``.  The
+wrapper applies the masked, power-weighted reduction in the *same
+expression* as :func:`repro.core.objectives.carbon`, so the kernel path
+equals the jnp gather path bitwise, not just allclose.  Start/end epochs
+are clamped into ``[0, H]`` exactly as the jnp oracle clips them;
+candidates overrunning the trace (routine for infeasible SA proposals
+before the penalty prices them) integrate to the trace edge.
 """
 from __future__ import annotations
 
@@ -40,48 +39,65 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
+SUBLANE = 8
+UNROLL = 8
 
 
-def _kernel(start_ref, dur_ref, cum_ref, out_ref, *, n_slabs: int,
-            horizon: int):
-    """One (pop-block, task-block) tile.
+def _kernel(start_ref, dur_ref, cum_ref, out_ref, c1_ref, c0_ref, *,
+            block_h: int, horizon: int):
+    """One (slot-block, horizon-block) step.
 
-    start/dur: [bp, bt] i32; cum: [Hp] (full, VMEM-resident);
-    out: [bp, bt] f32 per-task deltas ``cum[e1] - cum[e0]``.
+    start/dur: [block_rows, 128] i32 task slots; cum: [1, block_h] f32 in
+    SMEM (epochs ``hb*block_h ..``); out: [block_rows, 128] f32 deltas,
+    written on the last horizon block; c1/c0: VMEM scratch carrying the
+    two selections across horizon blocks.
     """
-    s0 = jnp.clip(start_ref[...], 0, horizon)             # [bp, bt] i32
+    hb = pl.program_id(1)
+
+    @pl.when(hb == 0)
+    def _init():
+        c1_ref[...] = jnp.zeros(c1_ref.shape, jnp.float32)
+        c0_ref[...] = jnp.zeros(c0_ref.shape, jnp.float32)
+
+    e0 = jnp.clip(start_ref[...], 0, horizon)
     e1 = jnp.clip(start_ref[...] + dur_ref[...], 0, horizon)
+    base = hb * block_h
 
-    def slab(i, acc):
-        h0 = pl.multiple_of(i * LANE, LANE)
-        cum_slab = cum_ref[pl.ds(h0, LANE)]               # [LANE]
-        idx = jax.lax.broadcasted_iota(jnp.int32, (LANE,), 0) + h0
-        # delta contribution: +cum[e1] - cum[e0] via masked slab products.
-        m1 = (e1[..., None] == idx).astype(jnp.float32)
-        m0 = (s0[..., None] == idx).astype(jnp.float32)
-        # <= 2 nonzero terms per (p, t) row -> the dot is exact in f32
-        # (HIGHEST keeps the TPU MXU from dropping to bf16 passes).
-        return acc + jnp.einsum("pth,h->pt", m1 - m0, cum_slab,
-                                preferred_element_type=jnp.float32,
-                                precision=jax.lax.Precision.HIGHEST)
+    def step(j, carry):
+        c1, c0 = carry
+        for k in range(UNROLL):          # Mosaic unrolls fully or not at all
+            i = j * UNROLL + k
+            h = base + i
+            c = cum_ref[0, i]
+            c1 = jnp.where(e1 == h, c, c1)
+            c0 = jnp.where(e0 == h, c, c0)
+        return c1, c0
 
-    out_ref[...] = jax.lax.fori_loop(
-        0, n_slabs, slab, jnp.zeros(s0.shape, jnp.float32))
+    c1, c0 = jax.lax.fori_loop(0, block_h // UNROLL, step,
+                               (c1_ref[...], c0_ref[...]))
+    c1_ref[...] = c1
+    c0_ref[...] = c0
+
+    @pl.when(hb == pl.num_programs(1) - 1)
+    def _finish():
+        out_ref[...] = c1 - c0
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_pop", "block_task", "interpret"))
+                   static_argnames=("block_rows", "block_h", "interpret"))
 def schedule_delta_pallas(start: jnp.ndarray, dur: jnp.ndarray,
                           cum: jnp.ndarray, *, interpret: bool,
-                          block_pop: int = 8,
-                          block_task: int = 128) -> jnp.ndarray:
+                          block_rows: int = 32,
+                          block_h: int = 1024) -> jnp.ndarray:
     """start/dur [Pop, T] i32; cum [H+1] f32.  Returns the per-task trace
     deltas ``cum[clip(s+d)] - cum[clip(s)]`` as [Pop, T] f32.
 
-    Pads Pop/T to block multiples and H+1 to a lane multiple; end epochs
-    are clamped to the real horizon ``H`` (never the padding), matching
+    Flattens the Pop*T slots into lane-dense rows (padded to whole
+    blocks) and pads ``cum`` to whole horizon blocks; end epochs are
+    clamped to the real horizon ``H`` (never the padding), matching
     :func:`repro.core.objectives.carbon`'s clipping bit-exactly.
 
     ``interpret`` is **required**: callers go through
@@ -90,28 +106,37 @@ def schedule_delta_pallas(start: jnp.ndarray, dur: jnp.ndarray,
     mode — ``interpret=False`` compiles for TPU).
     """
     P, T = start.shape
-    Pp = -(-P // block_pop) * block_pop
-    Tp = -(-T // block_task) * block_task
+    n = P * T
+    rows = -(-n // LANE)
+    br = min(block_rows, -(-rows // SUBLANE) * SUBLANE)
+    rows_p = -(-rows // br) * br
     H1 = cum.shape[0]
-    Hp = -(-H1 // LANE) * LANE
+    bh = min(block_h, -(-H1 // LANE) * LANE)
+    Hp = -(-H1 // bh) * bh
 
-    pad2 = lambda a: jnp.pad(a, ((0, Pp - P), (0, Tp - T)))  # noqa: E731
-    startp = pad2(start)
-    durp = pad2(dur)
-    cump = jnp.pad(cum, (0, Hp - H1))
+    def slots(a):
+        a = jnp.pad(a.reshape(n).astype(jnp.int32), (0, rows_p * LANE - n))
+        return a.reshape(rows_p, LANE)
 
-    grid = (Pp // block_pop, Tp // block_task)
-    kernel = functools.partial(_kernel, n_slabs=Hp // LANE, horizon=H1 - 1)
+    # [blocks, 1, bh]: the trailing (1, bh) block equals the array's own
+    # trailing dims, which keeps the SMEM block legal when vmap prepends
+    # an instance axis.
+    cump = jnp.pad(cum.astype(jnp.float32), (0, Hp - H1)).reshape(
+        Hp // bh, 1, bh)
+    kernel = functools.partial(_kernel, block_h=bh, horizon=H1 - 1)
+    slot_spec = pl.BlockSpec((br, LANE), lambda r, h: (r, 0))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(rows_p // br, Hp // bh),
         in_specs=[
-            pl.BlockSpec((block_pop, block_task), lambda p, t: (p, t)),
-            pl.BlockSpec((block_pop, block_task), lambda p, t: (p, t)),
-            pl.BlockSpec((Hp,), lambda p, t: (0,)),
+            slot_spec,
+            slot_spec,
+            pl.BlockSpec((None, 1, bh), lambda r, h: (h, 0, 0),
+                         memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((block_pop, block_task), lambda p, t: (p, t)),
-        out_shape=jax.ShapeDtypeStruct((Pp, Tp), jnp.float32),
+        out_specs=slot_spec,
+        out_shape=jax.ShapeDtypeStruct((rows_p, LANE), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((br, LANE), jnp.float32)] * 2,
         interpret=interpret,
-    )(startp, durp, cump)
-    return out[:P, :T]
+    )(slots(start), slots(dur), cump)
+    return out.reshape(rows_p * LANE)[:n].reshape(P, T)

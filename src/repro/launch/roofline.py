@@ -27,8 +27,17 @@ import os
 from repro import configs
 from repro.models.common import SHAPES
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM).
+# A device that is not listed has no roofline: ``achieved_vs_roofline``
+# raises rather than divide its times into another chip's peaks.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+# The dry-run analysis below projects onto v5e pods.
+PEAK_FLOPS = DEVICE_PEAKS["TPU v5 lite"]["flops_per_s"]
+HBM_BW = DEVICE_PEAKS["TPU v5 lite"]["hbm_bytes_per_s"]
 LINK_BW = 50e9
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
@@ -135,34 +144,40 @@ def model_flops(arch: str, shape: str) -> float:
     return 2.0 * n * tokens
 
 
-def achieved_vs_roofline(cost: dict, warm_s: float) -> dict:
-    """Achieved vs roofline for one measured jitted program.
+def achieved_vs_roofline(cost: dict, host_warm_s: float,
+                         device_kind: str) -> dict:
+    """Host-timed achieved vs roofline for one jitted program on a device.
 
     ``cost`` is :func:`repro.launch.hlo_analysis.cost_dict` of the compiled
-    program; ``warm_s`` its measured warm wall-clock.  Returns the
-    achieved-FLOP/s / achieved-bytes/s columns the benchmark provenance
-    stamps into every BENCH_*.json, plus the roofline bound at the v5e
-    reference constants (``PEAK_FLOPS`` / ``HBM_BW``).  ``roofline_frac``
-    is bound-time / measured-time — on a TPU the fraction of the roofline
-    achieved; on the CPU backend it reads as headroom to the reference
-    accelerator (the perf gate tracks *warm_s* regressions either way,
-    machine-local).
+    program; ``host_warm_s`` its warm time on the host's clock around a
+    synced call on a device of kind ``device_kind``, whose published
+    peaks (:data:`DEVICE_PEAKS`) give the bound.  The host time holds
+    dispatch and sync overhead as well as device time, so the
+    ``host_timed_*`` fields are lower bounds on what the device achieved,
+    not device metrics; a device's own share needs kernel time from a
+    profiler trace.  A device kind with no published peaks raises
+    ``ValueError``: there is no roofline to compare against.
     """
+    peaks = DEVICE_PEAKS.get(device_kind)
+    if peaks is None:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(DEVICE_PEAKS)})")
     flops = float(cost.get("flops", 0.0))
     bytes_ = float(cost.get("bytes", 0.0))
-    warm_s = max(float(warm_s), 1e-12)
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_ / HBM_BW
+    host_warm_s = max(float(host_warm_s), 1e-12)
+    compute_s = flops / peaks["flops_per_s"]
+    memory_s = bytes_ / peaks["hbm_bytes_per_s"]
     bound_s = max(compute_s, memory_s)
     return {
+        "device_kind": device_kind,
         "hlo_flops": flops,
         "hlo_bytes": bytes_,
-        "achieved_flops_per_s": flops / warm_s,
-        "achieved_bytes_per_s": bytes_ / warm_s,
+        "host_timed_flops_per_s": flops / host_warm_s,
+        "host_timed_bytes_per_s": bytes_ / host_warm_s,
         "roofline_compute_s": compute_s,
         "roofline_memory_s": memory_s,
         "roofline_bound_s": bound_s,
-        "roofline_frac": bound_s / warm_s,
+        "host_timed_roofline_frac": bound_s / host_warm_s,
         "dominant": "compute" if compute_s >= memory_s else "memory",
     }
 

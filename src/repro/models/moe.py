@@ -30,9 +30,9 @@ from repro.models.common import ArchConfig
 from repro.models.layers import activation, cast
 from repro.models.params import ParamDef
 from repro.models.parallel import ParallelCfg
-# The jax.shard_map / jax.experimental.shard_map API bridge lives with the
+# The single shard_map entry point lives with the
 # instance-axis sharding layer; the EP psum makes this body's output fully
-# replicated, which the bridge's disabled checker can't prove (see there).
+# replicated, which its disabled checker can't prove (see there).
 from repro.shard.compat import shard_map_compat as _shard_map
 
 

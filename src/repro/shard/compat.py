@@ -1,11 +1,9 @@
-"""The single ``shard_map`` entry point across the JAX API move.
+"""The single ``shard_map`` entry point.
 
 Every ``shard_map`` call in the repo — the MoE expert-parallel path in
 :mod:`repro.models.moe` and the instance-axis sharding layer in
-:mod:`repro.shard` — routes through :func:`shard_map_compat`, so the
-``jax.shard_map`` / ``jax.experimental.shard_map`` API bridge lives in
-exactly one place (hoisted here from ``models/moe.py``, where it was born
-as the fix for the seed-era ``test_moe_train_step_on_8_devices`` failure).
+:mod:`repro.shard` — routes through :func:`shard_map_compat`, so its
+settings live in exactly one place.
 """
 from __future__ import annotations
 
@@ -13,19 +11,11 @@ import jax
 
 
 def shard_map_compat(body, *, mesh, in_specs, out_specs):
-    """``shard_map`` across the JAX API move, replication checks off.
+    """``jax.shard_map`` with the replication check off.
 
-    Newer JAX exposes ``jax.shard_map`` (replication checking via
-    ``check_vma``); older releases only have
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``.  The
-    callers' output collectives (MoE's psum, the instance layer's
+    The callers' output collectives (MoE's psum, the instance layer's
     all_gather) make outputs fully replicated where the specs say so, but
-    the checker can't prove it through scatters, so it is disabled under
-    whichever spelling the running JAX accepts.
+    the checker can't prove it through scatters, so ``check_vma`` is off.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -50,12 +50,9 @@ def is_initialized() -> bool:
 def _enable_cpu_collectives() -> None:
     """Select gloo for cross-process CPU collectives (the XLA default CPU
     collectives raise ``Multiprocess computations aren't implemented on
-    the CPU backend``).  Must run before the backend is created; harmless
-    on jax versions or backends where the option is absent."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:   # option renamed/absent — non-CPU backends don't care
-        pass
+    the CPU backend``).  Must run before the backend is created; other
+    backends ignore the option."""
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def initialize(coordinator: str | None = None,

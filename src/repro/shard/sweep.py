@@ -12,8 +12,8 @@ over the instance axis:
   dispatch / bound / learn programs through this package), so benchmarks
   and tests have one sharded front door.
 
-Bit-exact with the single-device sweep: per-row SA chains are driven by
-per-row keys and rows never interact.  Unlike the dispatch/train paths,
+Bit-exact with the single-device sweep on the CPU: per-row SA chains are
+driven by per-row keys and rows never interact.  Unlike the dispatch/train paths,
 the bound does **not** go through ``shard_map``: XLA's manual-partitioning
 pipeline fuses transcendentals (the ``erf_inv`` behind
 ``jax.random.normal``) a vector-ulp differently than the plain jit path,
@@ -21,8 +21,11 @@ and a one-ulp fitness difference can flip a stochastic-search
 accept/reject and diverge the whole SA trajectory.  Instead each device
 runs the *same compiled batched program* on its committed row shard —
 per-device program dispatch, which is asynchronous in JAX, so shards still
-execute concurrently — and the program is batch-size independent
-(``tests/test_shard.py`` locks that parity too).
+execute concurrently — and on the CPU the program is batch-size
+independent (``tests/test_shard.py`` locks that parity too).  On a TPU v5e
+it is not: the full structure-sweep grid's bound at 60 rows on each of 4
+chips and at 240 rows on one chip gave a different savings figure in
+every cell, while the dispatch rows matched.
 """
 from __future__ import annotations
 
@@ -35,26 +38,18 @@ from repro.core.solvers.bilevel import BilevelResult, solve_bilevel_batch
 from repro.shard.batch import _pad_rows, instance_mesh, round_up
 
 
-def bilevel_sharded(insts: PackedInstance, cums, keys,
-                    devices: int | None = None,
-                    processes: int | None = None, **kw) -> BilevelResult:
-    """``solve_bilevel_batch`` with the instance axis sharded.
+def bilevel_shards(insts: PackedInstance, cums, keys,
+                   devices: int | None = None,
+                   processes: int | None = None,
+                   **kw) -> list[BilevelResult]:
+    """Dispatch ``solve_bilevel_batch`` once per device of this process.
 
-    ``keys`` is the same ``[B]`` typed-key array the batched solver takes;
-    rows are padded to a device multiple (inert instances, zero keys),
-    each device solves its committed shard of rows with the identical
-    compiled program (see module docstring for why this path dispatches
-    per device instead of shard_mapping), and results come back
-    concatenated in row order, sliced to the real rows.
-
-    With ``processes=P`` (``devices`` per process) each process dispatches
-    only the contiguous row block its canonical process id owns — the same
-    per-device pattern, one level up — then
-    ``multihost_utils.process_allgather`` concatenates the blocks in
-    process-id order, which *is* canonical row order.  Each device still
-    runs the identical compiled program on identically-shaped shards, so
-    the SA trajectories — and therefore the bound — are bit-exact at any
-    (process count, device count) with the same total.
+    Rows are padded to a device multiple (inert instances, zero keys) and
+    each device solves its contiguous block of rows with the identical
+    compiled program (see the module docstring for why this path
+    dispatches per device instead of shard_mapping).  Returns this
+    process's per-device results, each still on its device, in row order;
+    :func:`bilevel_sharded` gathers them.
     """
     mesh = instance_mesh(devices, processes=processes)
     B = int(jnp.asarray(cums).shape[0])
@@ -83,6 +78,30 @@ def bilevel_sharded(insts: PackedInstance, cums, keys,
         args = jax.tree.map(lambda x: jax.device_put(x[sl], dev),
                             (insts_p, cums_p, keys))
         shards.append(solve_bilevel_batch(*args, **kw))   # async, on dev i
+    return shards
+
+
+def bilevel_sharded(insts: PackedInstance, cums, keys,
+                    devices: int | None = None,
+                    processes: int | None = None, **kw) -> BilevelResult:
+    """``solve_bilevel_batch`` with the instance axis sharded.
+
+    ``keys`` is the same ``[B]`` typed-key array the batched solver takes;
+    :func:`bilevel_shards` solves each device's block of rows, and the
+    results come back concatenated in row order, sliced to the real rows.
+
+    With ``processes=P`` (``devices`` per process) each process dispatches
+    only the contiguous row block its canonical process id owns — the same
+    per-device pattern, one level up — then
+    ``multihost_utils.process_allgather`` concatenates the blocks in
+    process-id order, which *is* canonical row order.  Each device still
+    runs the identical compiled program on identically-shaped shards, so
+    the SA trajectories — and therefore the bound — are bit-exact at any
+    (process count, device count) with the same total.
+    """
+    B = int(jnp.asarray(cums).shape[0])
+    shards = bilevel_shards(insts, cums, keys, devices=devices,
+                            processes=processes, **kw)
     out = jax.tree.map(lambda *xs: np.concatenate(
         [np.asarray(x) for x in xs]), *shards)
     if processes is not None:

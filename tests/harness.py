@@ -23,9 +23,12 @@ the one spawn path both kinds of test share:
   ``benchmarks/structure_sweep.py --tiny --processes 2 --devices 4``
   multi-process locally or in CI.
 
-Workers are spawned with ``PYTHONPATH`` covering ``src`` and the repo
-root, and with any inherited ``REPRO_*`` contract scrubbed first so a
-nested single-process payload never accidentally joins an outer fleet.
+Every worker is a fleet of fake CPU devices, so it is pinned to the CPU
+(``JAX_PLATFORMS=cpu``): on a host with an accelerator, a child that
+reached for it would contend with the parent, which holds it.  Workers are
+spawned with ``PYTHONPATH`` covering ``src`` and the repo root, and with
+any inherited ``REPRO_*`` contract scrubbed first so a nested
+single-process payload never accidentally joins an outer fleet.
 """
 from __future__ import annotations
 
@@ -57,6 +60,7 @@ def _worker_env(devices: int, extra: dict | None = None) -> dict:
     if env.get("PYTHONPATH"):
         path.append(env["PYTHONPATH"])
     env["PYTHONPATH"] = os.pathsep.join(path)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     for k in (ENV_COORDINATOR, ENV_NUM_PROCESSES, ENV_PROCESS_ID):
         env.pop(k, None)

@@ -4,6 +4,8 @@ Property tests (hypothesis) pin the feasibility invariants of the SGS
 decoder and timing sweep; the exact oracle certifies optimality on tiny
 instances (replacing the paper's CP-SAT ground truth).
 """
+from unittest import mock
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -13,7 +15,8 @@ import jax.numpy as jnp
 
 from repro.core import generate_instance, pack, synthesize, validate
 from repro.core.carbon import constant, sample_window
-from repro.core.decoder import sgs, timing_sweep, upward_rank
+from repro.core.decoder import (_select_row, sgs, sweep_table, timing_sweep,
+                                upward_rank)
 from repro.core.instance import DAG_SHAPES, Job, Instance
 from repro.core.objectives import (carbon, energy, evaluate, makespan,
                                    utilization)
@@ -115,6 +118,43 @@ def test_timing_sweep_docstring_invariants(seed, slack):
         c = float(carbon(p, s, dec.assign, cum))
         assert c <= prev + 1e-3                  # monotone across sweeps
         prev = c
+
+
+@settings(deadline=None, max_examples=25)
+@given(seed=st.integers(0, 10_000), slack=st.integers(0, 60))
+def test_timing_sweep_table_equals_gather(seed, slack):
+    """The sweep's one-hot table form (its TPU path, built here on the
+    CPU by reporting a TPU backend) selects every start-cost row bit for
+    bit as the gather form computes it, so whole sweeps agree exactly,
+    with padded and frozen tasks too."""
+    rng = np.random.default_rng(seed)
+    inst = generate_instance(rng, n_jobs=3, k_tasks=4, n_machines=3,
+                             heterogeneous=bool(seed % 2))
+    p = pack(inst, pad_tasks=16)
+    cum = _trace_cum(rng)
+    H = cum.shape[0] - 1
+    svec = jnp.arange(H + 1, dtype=jnp.int32)
+    assert sweep_table(p, cum) is None
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        table = sweep_table(p, cum)
+    rows = jax.vmap(lambda j: _select_row(table, j))(jnp.arange(p.T * p.M))
+    want = jnp.stack([cum[jnp.minimum(svec + p.dur[t, m], H)] - cum[svec]
+                      for t in range(p.T) for m in range(p.M)])
+    assert np.array_equal(np.asarray(rows), np.asarray(want))
+
+    prio = jnp.asarray(rng.normal(size=(8, p.T)), jnp.float32)
+    dec = jax.vmap(lambda q: sgs(p, q))(prio)
+    deadline = jnp.int32(int(jnp.max(jax.vmap(
+        lambda s, a: makespan(p, s, a))(dec.start, dec.assign))) + slack)
+    frozen = jnp.asarray(rng.random(p.T) < 0.25)
+    for fz in (None, frozen):
+        sweep = lambda s, a, tb: timing_sweep(  # noqa: E731
+            p, s, a, cum, deadline, sweeps=2, frozen=fz, table=tb)
+        got = jax.vmap(lambda s, a: sweep(s, a, table))(dec.start,
+                                                          dec.assign)
+        ref = jax.vmap(lambda s, a: sweep(s, a, None))(dec.start,
+                                                         dec.assign)
+        assert np.array_equal(np.asarray(got), np.asarray(ref))
 
 
 def test_upward_rank_tops_roots(rng):

@@ -101,7 +101,7 @@ def _assert_grad_matches_fd(seed, family, theta):
     difference needs f64 resolution; theta values sit away from the
     quantile-interpolation knots ``j / (n - 1)``.
     """
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         p, inten, cum, sv, n, budget = _loss_parts(seed, family, "tiered",
                                                    dtype=jnp.float64)
         E = int(inten.shape[0])

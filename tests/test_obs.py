@@ -14,7 +14,7 @@ Four groups:
 * **harness** — fake-clock BenchTimer (cold/warm split is arithmetic,
   locked without real timing), perf-gate verdict logic on fake probes
   (regression / pass / fingerprint-skip / no-baseline skip), provenance
-  checks, and the roofline arithmetic.
+  checks, and the roofline arithmetic (device-keyed peaks).
 """
 import dataclasses
 import json
@@ -339,13 +339,49 @@ def test_check_provenance(tmp_path):
 
 
 def test_roofline_achieved_columns():
-    from repro.launch.roofline import (HBM_BW, PEAK_FLOPS,
-                                       achieved_vs_roofline)
-    cost = {"flops": 2 * PEAK_FLOPS, "bytes": HBM_BW / 2}
-    out = achieved_vs_roofline(cost, warm_s=4.0)
+    from repro.launch.roofline import DEVICE_PEAKS, achieved_vs_roofline
+    peaks = DEVICE_PEAKS["TPU v5 lite"]
+    cost = {"flops": 2 * peaks["flops_per_s"],
+            "bytes": peaks["hbm_bytes_per_s"] / 2}
+    out = achieved_vs_roofline(cost, host_warm_s=4.0,
+                               device_kind="TPU v5 lite")
+    assert out["device_kind"] == "TPU v5 lite"
     assert out["roofline_compute_s"] == pytest.approx(2.0)
     assert out["roofline_memory_s"] == pytest.approx(0.5)
     assert out["dominant"] == "compute"
     assert out["roofline_bound_s"] == pytest.approx(2.0)
-    assert out["roofline_frac"] == pytest.approx(0.5)
-    assert out["achieved_flops_per_s"] == pytest.approx(PEAK_FLOPS / 2)
+    assert out["host_timed_roofline_frac"] == pytest.approx(0.5)
+    assert out["host_timed_flops_per_s"] == pytest.approx(
+        peaks["flops_per_s"] / 2)
+
+
+def test_compile_cache_dir_env_wins_else_fixed_repo_path(monkeypatch,
+                                                         tmp_path):
+    """The environment's cache directory is left to JAX; otherwise the
+    cache goes to one fixed, gitignored path in the checkout."""
+    import os
+
+    import jax
+
+    from benchmarks import common
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert common.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = common.use_compile_cache()
+        assert path == os.path.join(common.REPO_ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        with open(os.path.join(common.REPO_ROOT, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_roofline_unknown_device_kind_raises():
+    """A device with no published peaks has no roofline — never a default."""
+    from repro.launch.roofline import achieved_vs_roofline
+    with pytest.raises(ValueError, match="no published peaks"):
+        achieved_vs_roofline({"flops": 1.0, "bytes": 1.0}, host_warm_s=1.0,
+                             device_kind="cpu")
