@@ -167,10 +167,9 @@ class BenchTimer:
 # The pinned perf-probe cells.  Tiny, seed-pinned, shape-static programs
 # covering the two hot paths (the dispatch sweep and the gate-learner
 # step), compiled AOT so the probe measures compile and warm wall-clock
-# separately AND captures the compiled program's HLO cost analysis for the
-# achieved-vs-roofline columns.  Every benchmark stamps probe results into
-# its BENCH_*.json; benchmarks/perf_gate.py compares fresh probe warm
-# medians against those stored baselines.
+# separately.  Every benchmark stamps probe results into its
+# BENCH_*.json; benchmarks/perf_gate.py compares fresh probe warm medians
+# against those stored baselines.
 # ---------------------------------------------------------------------------
 
 PROBE_SEED = 7
@@ -267,33 +266,23 @@ PROBE_CELLS = {
 
 
 def _probe_cell(build: Callable, timer: BenchTimer) -> dict:
-    from repro.launch.hlo_analysis import cost_dict, memory_dict
-    from repro.launch.roofline import DEVICE_PEAKS, achieved_vs_roofline
+    from repro.launch.hlo_analysis import memory_dict
     lowered, args = build()
     t0 = timer.clock()
     compiled = lowered.compile()
     compile_s = timer.clock() - t0
     warms = [timer.timed(compiled, *args)[1]
              for _ in range(PROBE_WARM_REPS)]
-    warm_median = float(np.median(warms))
-    cell = {
+    return {
         "compile_s": round(compile_s, 6),
         # warm_s_min is the gate quantity (noise-robust on shared hosts:
         # the best rep is the program's floor, medians carry OS jitter);
         # the median/all columns stay for reading run-to-run variance.
         "warm_s_min": round(float(np.min(warms)), 6),
-        "warm_s_median": round(warm_median, 6),
+        "warm_s_median": round(float(np.median(warms)), 6),
         "warm_s_all": [round(w, 6) for w in warms],
         "memory": memory_dict(compiled),
     }
-    # A roofline exists only for a device with published peaks; a CPU
-    # time divided into a TPU's peaks is no device metric.  On the chip
-    # the block is host-timed (see ``achieved_vs_roofline``).
-    kind = jax.devices()[0].device_kind
-    if kind in DEVICE_PEAKS:
-        cell["host_timed_roofline"] = achieved_vs_roofline(
-            cost_dict(compiled), warm_median, kind)
-    return cell
 
 
 @functools.lru_cache(maxsize=1)
@@ -311,9 +300,9 @@ def perf_probe(fresh: bool = False) -> dict:
     """Compile + time the pinned probe cells (cached per process).
 
     AOT compile is timed apart from ``PROBE_WARM_REPS`` synced warm calls,
-    and each cell carries the compiled program's achieved-vs-roofline
-    record.  This dict is what benchmarks stamp under ``timing.probe`` and
-    what ``benchmarks/perf_gate.py`` compares against stored baselines.
+    and each cell carries the compiled program's memory analysis.  This
+    dict is what benchmarks stamp under ``timing.probe`` and what
+    ``benchmarks/perf_gate.py`` compares against stored baselines.
     """
     if fresh:
         _cached_probe.cache_clear()

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.core.instance import PackedInstance
 from repro.core.objectives import task_durations
+from repro.obs.scopes import scope
 
 BIG = jnp.int32(1 << 28)
 
@@ -58,47 +59,48 @@ def sgs(inst: PackedInstance, prio: jnp.ndarray,
     """
     if machine_rule not in MACHINE_RULES:
         raise ValueError(f"unknown machine_rule {machine_rule!r}")
-    T, M = inst.T, inst.M
-    real = inst.task_mask
-    pred_real = inst.pred & real[None, :]
-    if assign is None:
-        assign = jnp.zeros((T,), jnp.int32)
+    with scope("sgs"):
+        T, M = inst.T, inst.M
+        real = inst.task_mask
+        pred_real = inst.pred & real[None, :]
+        if assign is None:
+            assign = jnp.zeros((T,), jnp.int32)
 
-    def body(state, i):
-        scheduled, comp, mfree, start, aout, seq = state
-        pending = jnp.any(pred_real & ~scheduled[None, :], axis=1)
-        ready = ~scheduled & ~pending
-        t = jnp.argmax(jnp.where(ready, prio, -jnp.inf))
-        pred_comp = jnp.max(jnp.where(pred_real[t], comp, 0))
-        base = jnp.maximum(inst.arrival[t], pred_comp)
-        est_m = jnp.maximum(base, mfree)               # [M]
-        dur_t = inst.dur[t]                            # [M]
-        fin_m = est_m + dur_t
-        ok = inst.allowed[t]
-        if machine_rule == "fixed":
-            m = assign[t]
-        elif machine_rule == "earliest_finish":
-            m = jnp.argmin(jnp.where(ok, fin_m, BIG)).astype(jnp.int32)
-        else:  # min_energy
-            cost = inst.power * dur_t.astype(jnp.float32)
-            key = jnp.where(ok, cost * 65536.0 + fin_m.astype(jnp.float32),
-                            jnp.float32(3e38))
-            m = jnp.argmin(key).astype(jnp.int32)
-        s = est_m[m]
-        c = s + dur_t[m]
-        return (scheduled.at[t].set(True),
-                comp.at[t].set(c),
-                mfree.at[m].set(jnp.maximum(mfree[m], c)),
-                start.at[t].set(s),
-                aout.at[t].set(m),
-                seq.at[t].set(i)), None
+        def body(state, i):
+            scheduled, comp, mfree, start, aout, seq = state
+            pending = jnp.any(pred_real & ~scheduled[None, :], axis=1)
+            ready = ~scheduled & ~pending
+            t = jnp.argmax(jnp.where(ready, prio, -jnp.inf))
+            pred_comp = jnp.max(jnp.where(pred_real[t], comp, 0))
+            base = jnp.maximum(inst.arrival[t], pred_comp)
+            est_m = jnp.maximum(base, mfree)               # [M]
+            dur_t = inst.dur[t]                            # [M]
+            fin_m = est_m + dur_t
+            ok = inst.allowed[t]
+            if machine_rule == "fixed":
+                m = assign[t]
+            elif machine_rule == "earliest_finish":
+                m = jnp.argmin(jnp.where(ok, fin_m, BIG)).astype(jnp.int32)
+            else:  # min_energy
+                cost = inst.power * dur_t.astype(jnp.float32)
+                key = jnp.where(ok, cost * 65536.0 + fin_m.astype(jnp.float32),
+                                jnp.float32(3e38))
+                m = jnp.argmin(key).astype(jnp.int32)
+            s = est_m[m]
+            c = s + dur_t[m]
+            return (scheduled.at[t].set(True),
+                    comp.at[t].set(c),
+                    mfree.at[m].set(jnp.maximum(mfree[m], c)),
+                    start.at[t].set(s),
+                    aout.at[t].set(m),
+                    seq.at[t].set(i)), None
 
-    init = (jnp.zeros((T,), bool), jnp.zeros((T,), jnp.int32),
-            jnp.zeros((M,), jnp.int32), jnp.zeros((T,), jnp.int32),
-            jnp.zeros((T,), jnp.int32), jnp.zeros((T,), jnp.int32))
-    (_, _, _, start, aout, seq), _ = jax.lax.scan(
-        body, init, jnp.arange(T, dtype=jnp.int32))
-    return DecodedSchedule(start, aout, seq)
+        init = (jnp.zeros((T,), bool), jnp.zeros((T,), jnp.int32),
+                jnp.zeros((M,), jnp.int32), jnp.zeros((T,), jnp.int32),
+                jnp.zeros((T,), jnp.int32), jnp.zeros((T,), jnp.int32))
+        (_, _, _, start, aout, seq), _ = jax.lax.scan(
+            body, init, jnp.arange(T, dtype=jnp.int32))
+        return DecodedSchedule(start, aout, seq)
 
 
 def sweep_table(inst: PackedInstance, cum: jnp.ndarray) -> tuple | None:
@@ -124,16 +126,17 @@ def sweep_table(inst: PackedInstance, cum: jnp.ndarray) -> tuple | None:
     if cum.dtype != jnp.float32:
         raise TypeError(f"sweep_table splits f32 rows exactly; cum is "
                         f"{cum.dtype}")
-    T, M = inst.T, inst.M
-    H = cum.shape[0] - 1
-    svec = jnp.arange(H + 1, dtype=jnp.int32)
-    end = jnp.minimum(svec + inst.dur[:, :, None], H)        # [T, M, H+1]
-    rows = (cum[end] - cum[svec]).reshape(T * M, H + 1)
-    b1 = rows.astype(jnp.bfloat16)
-    r1 = rows - b1.astype(jnp.float32)
-    b2 = r1.astype(jnp.bfloat16)
-    b3 = (r1 - b2.astype(jnp.float32)).astype(jnp.bfloat16)
-    return b1, b2, b3
+    with scope("sweep_table"):
+        T, M = inst.T, inst.M
+        H = cum.shape[0] - 1
+        svec = jnp.arange(H + 1, dtype=jnp.int32)
+        end = jnp.minimum(svec + inst.dur[:, :, None], H)        # [T, M, H+1]
+        rows = (cum[end] - cum[svec]).reshape(T * M, H + 1)
+        b1 = rows.astype(jnp.bfloat16)
+        r1 = rows - b1.astype(jnp.float32)
+        b2 = r1.astype(jnp.bfloat16)
+        b3 = (r1 - b2.astype(jnp.float32)).astype(jnp.bfloat16)
+        return b1, b2, b3
 
 
 def _select_row(table: tuple, j: jnp.ndarray) -> jnp.ndarray:
@@ -174,46 +177,47 @@ def timing_sweep(inst: PackedInstance, start: jnp.ndarray,
     not given; a solver that sweeps many candidates of one instance builds
     it once and passes it (XLA leaves it inside the search loop).
     """
-    T, M = inst.T, inst.M
-    H = cum.shape[0] - 1
-    d = task_durations(inst, assign)
-    real = inst.task_mask
-    sweepable = real if frozen is None else real & ~frozen
-    svec = jnp.arange(H + 1, dtype=jnp.int32)
-    if table is None:
-        table = sweep_table(inst, cum)
-    same_m = (assign[:, None] == assign[None, :]) & real[None, :]
-    succ = inst.pred.T & real[None, :]          # succ[t, v]: t -> v edge
+    with scope("timing_sweep"):
+        T, M = inst.T, inst.M
+        H = cum.shape[0] - 1
+        d = task_durations(inst, assign)
+        real = inst.task_mask
+        sweepable = real if frozen is None else real & ~frozen
+        svec = jnp.arange(H + 1, dtype=jnp.int32)
+        if table is None:
+            table = sweep_table(inst, cum)
+        same_m = (assign[:, None] == assign[None, :]) & real[None, :]
+        succ = inst.pred.T & real[None, :]          # succ[t, v]: t -> v edge
 
-    def one_sweep(start):
-        # Freeze the sequence key for this sweep: (start, idx) descending.
-        key = start * jnp.int32(T) + jnp.arange(T, dtype=jnp.int32)
-        order = jnp.argsort(-jnp.where(real, key, -BIG))  # pads last
+        def one_sweep(start):
+            # Freeze the sequence key for this sweep: (start, idx) descending.
+            key = start * jnp.int32(T) + jnp.arange(T, dtype=jnp.int32)
+            order = jnp.argsort(-jnp.where(real, key, -BIG))  # pads last
 
-        def body(start_cur, t):
-            dt = d[t]
-            succ_cap = jnp.min(jnp.where(succ[t], start_cur, BIG))
-            after = same_m[t] & (key > key[t])
-            mnext_cap = jnp.min(jnp.where(after, start_cur, BIG))
-            hi = jnp.minimum(jnp.minimum(succ_cap, mnext_cap),
-                             deadline.astype(jnp.int32)) - dt
-            lo = start_cur[t]
-            if table is None:
-                cost = cum[jnp.minimum(svec + dt, H)] - cum[svec]
-            else:
-                cost = _select_row(table, t * M + assign[t])
-            cost = jnp.where((svec >= lo) & (svec <= hi), cost, jnp.inf)
-            s_star = jnp.argmin(cost).astype(jnp.int32)
-            movable = sweepable[t] & (hi >= lo)
-            new_s = jnp.where(movable, s_star, start_cur[t])
-            return start_cur.at[t].set(new_s), None
+            def body(start_cur, t):
+                dt = d[t]
+                succ_cap = jnp.min(jnp.where(succ[t], start_cur, BIG))
+                after = same_m[t] & (key > key[t])
+                mnext_cap = jnp.min(jnp.where(after, start_cur, BIG))
+                hi = jnp.minimum(jnp.minimum(succ_cap, mnext_cap),
+                                 deadline.astype(jnp.int32)) - dt
+                lo = start_cur[t]
+                if table is None:
+                    cost = cum[jnp.minimum(svec + dt, H)] - cum[svec]
+                else:
+                    cost = _select_row(table, t * M + assign[t])
+                cost = jnp.where((svec >= lo) & (svec <= hi), cost, jnp.inf)
+                s_star = jnp.argmin(cost).astype(jnp.int32)
+                movable = sweepable[t] & (hi >= lo)
+                new_s = jnp.where(movable, s_star, start_cur[t])
+                return start_cur.at[t].set(new_s), None
 
-        start, _ = jax.lax.scan(body, start, order)
+            start, _ = jax.lax.scan(body, start, order)
+            return start
+
+        for _ in range(sweeps):
+            start = one_sweep(start)
         return start
-
-    for _ in range(sweeps):
-        start = one_sweep(start)
-    return start
 
 
 @jax.jit

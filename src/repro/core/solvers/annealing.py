@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.core.decoder import sweep_table, upward_rank
 from repro.core.instance import PackedInstance
 from repro.core.solvers import common
+from repro.obs.scopes import scope
 
 
 class SAConfig(NamedTuple):
@@ -63,89 +64,92 @@ def solve_sa(inst: PackedInstance, cum: jnp.ndarray, deadline: jnp.ndarray,
     defers to ``REPRO_KERNELS`` / the backend default, see
     :func:`repro.core.solvers.common.population_fitness`.
     """
-    T = inst.T
-    free = (jnp.ones((T,), bool) if frozen is None else ~frozen)
-    sweeps = 0 if objective == "makespan" else cfg.sweeps
-    table = sweep_table(inst, cum) if sweeps else None
-    fit_v = lambda p, a: common.population_fitness(  # noqa: E731
-        inst, cum, deadline, p, a, objective, machine_rule, sweeps,
-        frozen=frozen, use_kernels=use_kernels, table=table)
+    with scope("search"):
+        T = inst.T
+        free = (jnp.ones((T,), bool) if frozen is None else ~frozen)
+        sweeps = 0 if objective == "makespan" else cfg.sweeps
+        table = sweep_table(inst, cum) if sweeps else None
+        fit_v = lambda p, a: common.population_fitness(  # noqa: E731
+            inst, cum, deadline, p, a, objective, machine_rule, sweeps,
+            frozen=frozen, use_kernels=use_kernels, table=table)
 
-    k_init, k_assign, k_run = jax.random.split(key, 3)
-    rank = upward_rank(inst)
-    if prio_init is None:
-        prio_init = rank
-    prio = (prio_init[None, :]
-            + cfg.sigma * jax.random.normal(k_init, (cfg.pop, T)) * free)
-    # Keep one undisturbed copy of the init (chain 0).
-    prio = prio.at[0].set(prio_init)
-    if assign_init is None:
-        assign = common.random_allowed_assign(k_assign, inst, (cfg.pop,))
-    else:
-        assign = jnp.broadcast_to(assign_init, (cfg.pop, T)).astype(jnp.int32)
-    fit = fit_v(prio, assign)
+        k_init, k_assign, k_run = jax.random.split(key, 3)
+        rank = upward_rank(inst)
+        if prio_init is None:
+            prio_init = rank
+        prio = (prio_init[None, :]
+                + cfg.sigma * jax.random.normal(k_init, (cfg.pop, T)) * free)
+        # Keep one undisturbed copy of the init (chain 0).
+        prio = prio.at[0].set(prio_init)
+        if assign_init is None:
+            assign = common.random_allowed_assign(k_assign, inst, (cfg.pop,))
+        else:
+            assign = jnp.broadcast_to(assign_init, (cfg.pop, T)
+                                      ).astype(jnp.int32)
+        fit = fit_v(prio, assign)
 
-    spread = jnp.percentile(fit, 75) - jnp.percentile(fit, 25)
-    t0 = cfg.t0_frac * jnp.maximum(spread, 1e-3)
+        spread = jnp.percentile(fit, 75) - jnp.percentile(fit, 25)
+        t0 = cfg.t0_frac * jnp.maximum(spread, 1e-3)
 
-    b0 = jnp.argmin(fit)
-    best = (prio[b0], assign[b0], fit[b0])
+        b0 = jnp.argmin(fit)
+        best = (prio[b0], assign[b0], fit[b0])
 
-    def step(carry, it):
-        key, prio, assign, fit, best = carry
-        key, k1, k2, k3, k4, k5, k6 = jax.random.split(key, 7)
-        temp = t0 * cfg.t_decay ** it
+        def step(carry, it):
+            key, prio, assign, fit, best = carry
+            key, k1, k2, k3, k4, k5, k6 = jax.random.split(key, 7)
+            temp = t0 * cfg.t_decay ** it
 
-        # Priority proposal: gaussian noise on a random ~2-task subset.
-        mask = jax.random.bernoulli(k1, 2.0 / T, (cfg.pop, T)) & free
-        dp = cfg.sigma * jax.random.normal(k2, (cfg.pop, T)) * mask
-        new_prio = prio + dp
-        # Machine proposal: with prob p, reassign one random task.
-        do_m = jax.random.bernoulli(k3, cfg.p_machine_move, (cfg.pop,))
-        t_idx = jax.random.randint(k4, (cfg.pop,), 0, T)
-        new_m = common.random_allowed_assign(k5, inst, (cfg.pop,))
-        picked = jnp.take_along_axis(new_m, t_idx[:, None], 1)[:, 0]
-        new_assign = jnp.where(
-            (jnp.arange(T)[None, :] == t_idx[:, None]) & do_m[:, None],
-            picked[:, None], assign)
+            # Priority proposal: gaussian noise on a random ~2-task subset.
+            mask = jax.random.bernoulli(k1, 2.0 / T, (cfg.pop, T)) & free
+            dp = cfg.sigma * jax.random.normal(k2, (cfg.pop, T)) * mask
+            new_prio = prio + dp
+            # Machine proposal: with prob p, reassign one random task.
+            do_m = jax.random.bernoulli(k3, cfg.p_machine_move, (cfg.pop,))
+            t_idx = jax.random.randint(k4, (cfg.pop,), 0, T)
+            new_m = common.random_allowed_assign(k5, inst, (cfg.pop,))
+            picked = jnp.take_along_axis(new_m, t_idx[:, None], 1)[:, 0]
+            new_assign = jnp.where(
+                (jnp.arange(T)[None, :] == t_idx[:, None]) & do_m[:, None],
+                picked[:, None], assign)
 
-        new_fit = fit_v(new_prio, new_assign)
-        u = jax.random.uniform(k6, (cfg.pop,))
-        accept = (new_fit < fit) | (u < jnp.exp(-(new_fit - fit)
-                                                / jnp.maximum(temp, 1e-6)))
-        prio = jnp.where(accept[:, None], new_prio, prio)
-        assign = jnp.where(accept[:, None], new_assign, assign)
-        fit = jnp.where(accept, new_fit, fit)
+            new_fit = fit_v(new_prio, new_assign)
+            u = jax.random.uniform(k6, (cfg.pop,))
+            accept = (new_fit < fit) | (u < jnp.exp(-(new_fit - fit)
+                                                    / jnp.maximum(temp, 1e-6)))
+            prio = jnp.where(accept[:, None], new_prio, prio)
+            assign = jnp.where(accept[:, None], new_assign, assign)
+            fit = jnp.where(accept, new_fit, fit)
 
-        # Track global best.
-        i = jnp.argmin(fit)
-        bp, ba, bf = best
-        better = fit[i] < bf
-        best = (jnp.where(better, prio[i], bp),
-                jnp.where(better, assign[i], ba),
-                jnp.where(better, fit[i], bf))
+            # Track global best.
+            i = jnp.argmin(fit)
+            bp, ba, bf = best
+            better = fit[i] < bf
+            best = (jnp.where(better, prio[i], bp),
+                    jnp.where(better, assign[i], ba),
+                    jnp.where(better, fit[i], bf))
 
-        # Migration: worst quartile <- best + fresh noise.
-        def migrate(args):
-            key, prio, assign, fit = args
-            kk1, kk2 = jax.random.split(key)
-            thresh = jnp.percentile(fit, 75)
-            worst = fit >= thresh
-            mp = best[0][None, :] + cfg.sigma * jax.random.normal(
-                kk1, (cfg.pop, T)) * free
-            prio = jnp.where(worst[:, None], mp, prio)
-            assign = jnp.where(worst[:, None],
-                               jnp.broadcast_to(best[1], (cfg.pop, T)), assign)
-            fit = jnp.where(worst, fit_v(prio, assign), fit)
-            return prio, assign, fit
+            # Migration: worst quartile <- best + fresh noise.
+            def migrate(args):
+                key, prio, assign, fit = args
+                kk1, kk2 = jax.random.split(key)
+                thresh = jnp.percentile(fit, 75)
+                worst = fit >= thresh
+                mp = best[0][None, :] + cfg.sigma * jax.random.normal(
+                    kk1, (cfg.pop, T)) * free
+                prio = jnp.where(worst[:, None], mp, prio)
+                assign = jnp.where(worst[:, None],
+                                   jnp.broadcast_to(best[1], (cfg.pop, T)),
+                                   assign)
+                fit = jnp.where(worst, fit_v(prio, assign), fit)
+                return prio, assign, fit
 
-        key, km = jax.random.split(key)
-        prio, assign, fit = jax.lax.cond(
-            (it % cfg.migrate_every) == cfg.migrate_every - 1,
-            migrate, lambda a: (a[1], a[2], a[3]), (km, prio, assign, fit))
-        return (key, prio, assign, fit, best), None
+            key, km = jax.random.split(key)
+            prio, assign, fit = jax.lax.cond(
+                (it % cfg.migrate_every) == cfg.migrate_every - 1,
+                migrate, lambda a: (a[1], a[2], a[3]), (km, prio, assign, fit))
+            return (key, prio, assign, fit, best), None
 
-    (_, _, _, _, best), _ = jax.lax.scan(
-        step, (k_run, prio, assign, fit, best),
-        jnp.arange(cfg.iters, dtype=jnp.int32))
-    return SolveOut(*best)
+        (_, _, _, _, best), _ = jax.lax.scan(
+            step, (k_run, prio, assign, fit, best),
+            jnp.arange(cfg.iters, dtype=jnp.int32))
+        return SolveOut(*best)

@@ -27,6 +27,7 @@ from repro.core.instance import PackedInstance
 from repro.core.solvers import common
 from repro.core.solvers.annealing import SAConfig, solve_sa
 from repro.core.solvers.genetic import GAConfig, solve_ga
+from repro.obs.scopes import scope
 
 NO_DEADLINE = jnp.int32(1 << 27)
 
@@ -65,40 +66,42 @@ def solve_bilevel(inst: PackedInstance, cum: jnp.ndarray, key: jax.Array,
     k1, k2 = jax.random.split(key)
 
     # ---- Phase 1: makespan-only (the carbon-agnostic baseline). ----------
-    p1 = solve(inst, cum, NO_DEADLINE, k1, objective="makespan",
-               machine_rule="earliest_finish", cfg=cfg1,
-               use_kernels=use_kernels)
-    baseline = common.decode_full(
-        inst, cum, NO_DEADLINE, p1.prio, p1.assign,
-        objective="makespan", machine_rule="earliest_finish", sweeps=0)
-    opt_ms = baseline.makespan
-    deadline = jnp.floor(stretch * opt_ms.astype(jnp.float32) + 1e-6
-                         ).astype(jnp.int32)
+    with scope("phase1"):
+        p1 = solve(inst, cum, NO_DEADLINE, k1, objective="makespan",
+                   machine_rule="earliest_finish", cfg=cfg1,
+                   use_kernels=use_kernels)
+        baseline = common.decode_full(
+            inst, cum, NO_DEADLINE, p1.prio, p1.assign,
+            objective="makespan", machine_rule="earliest_finish", sweeps=0)
+        opt_ms = baseline.makespan
+        deadline = jnp.floor(stretch * opt_ms.astype(jnp.float32) + 1e-6
+                             ).astype(jnp.int32)
 
     # ---- Phase 2: carbon/energy under makespan <= S * OPT. ---------------
     # Warm start: the baseline's own (sequence, assignment) is feasible.
-    p2 = solve(inst, cum, deadline, k2, objective=objective,
-               machine_rule="fixed", cfg=cfg2,
-               prio_init=-baseline.start.astype(jnp.float32),
-               assign_init=baseline.assign, use_kernels=use_kernels)
-    table = sweep_table(inst, cum)
-    optimized = common.decode_full(
-        inst, cum, deadline, p2.prio, p2.assign,
-        objective=objective, machine_rule="fixed", sweeps=max(
-            getattr(cfg2, "sweeps", 2), 1), table=table)
+    with scope("phase2"):
+        p2 = solve(inst, cum, deadline, k2, objective=objective,
+                   machine_rule="fixed", cfg=cfg2,
+                   prio_init=-baseline.start.astype(jnp.float32),
+                   assign_init=baseline.assign, use_kernels=use_kernels)
+        table = sweep_table(inst, cum)
+        optimized = common.decode_full(
+            inst, cum, deadline, p2.prio, p2.assign,
+            objective=objective, machine_rule="fixed", sweeps=max(
+                getattr(cfg2, "sweeps", 2), 1), table=table)
 
-    # Guard: if phase 2 somehow ended worse (it cannot, given the warm start
-    # chain is kept, but belt-and-braces), fall back to the timing-swept
-    # baseline which is feasible by construction.
-    fallback = common.decode_full(
-        inst, cum, deadline, -baseline.start.astype(jnp.float32),
-        baseline.assign, objective=objective, machine_rule="fixed",
-        sweeps=max(getattr(cfg2, "sweeps", 2), 1), table=table)
-    key_obj = {"carbon": 4, "energy": 3}[objective]
-    use_fb = (optimized[key_obj] > fallback[key_obj]) | \
-        (optimized.makespan > deadline)
-    optimized = jax.tree.map(
-        lambda a, b: jnp.where(use_fb, b, a), optimized, fallback)
+        # Guard: if phase 2 somehow ended worse (it cannot, given the warm
+        # start chain is kept, but belt-and-braces), fall back to the
+        # timing-swept baseline which is feasible by construction.
+        fallback = common.decode_full(
+            inst, cum, deadline, -baseline.start.astype(jnp.float32),
+            baseline.assign, objective=objective, machine_rule="fixed",
+            sweeps=max(getattr(cfg2, "sweeps", 2), 1), table=table)
+        key_obj = {"carbon": 4, "energy": 3}[objective]
+        use_fb = (optimized[key_obj] > fallback[key_obj]) | \
+            (optimized.makespan > deadline)
+        optimized = jax.tree.map(
+            lambda a, b: jnp.where(use_fb, b, a), optimized, fallback)
 
     return BilevelResult(
         opt_makespan=opt_ms,

@@ -35,6 +35,7 @@ from repro.core.instance import PackedInstance
 from repro.core.objectives import Objectives, energy, evaluate, utilization
 from repro.core.validate import total_violations
 from repro.kernels import ops
+from repro.obs.scopes import scope
 
 OBJECTIVES = ("makespan", "carbon", "energy")
 VIOLATION_PENALTY = 1e5      # fitness units per unit of validator mass
@@ -71,9 +72,11 @@ def decode_full(inst: PackedInstance, cum: jnp.ndarray, deadline: jnp.ndarray,
     if objective != "makespan" and sweeps > 0:
         start = timing_sweep(inst, start, dec.assign, cum, deadline, sweeps,
                              frozen=frozen, table=table)
-    obj: Objectives = evaluate(inst, start, dec.assign, cum)
-    return ScheduleResult(start, dec.assign, obj.makespan, obj.energy,
-                          obj.carbon, utilization(inst, start, dec.assign))
+    with scope("objectives"):
+        obj: Objectives = evaluate(inst, start, dec.assign, cum)
+        return ScheduleResult(start, dec.assign, obj.makespan, obj.energy,
+                              obj.carbon,
+                              utilization(inst, start, dec.assign))
 
 
 def fitness_of(inst: PackedInstance, res: ScheduleResult,
@@ -85,15 +88,16 @@ def fitness_of(inst: PackedInstance, res: ScheduleResult,
     epochs past ``deadline``) — zero iff the schedule is feasible, so the
     unconstrained search and the feasibility tests agree on what counts.
     """
-    if objective == "makespan":
-        return res.makespan.astype(jnp.float32)
-    pen = VIOLATION_PENALTY * total_violations(
-        inst, res.start, res.assign, deadline).astype(jnp.float32)
-    if objective == "carbon":
-        return res.carbon + pen
-    if objective == "energy":
-        return res.energy + ENERGY_CARBON_TIEBREAK * res.carbon + pen
-    raise ValueError(f"unknown objective {objective!r}")
+    with scope("objectives"):
+        if objective == "makespan":
+            return res.makespan.astype(jnp.float32)
+        pen = VIOLATION_PENALTY * total_violations(
+            inst, res.start, res.assign, deadline).astype(jnp.float32)
+        if objective == "carbon":
+            return res.carbon + pen
+        if objective == "energy":
+            return res.energy + ENERGY_CARBON_TIEBREAK * res.carbon + pen
+        raise ValueError(f"unknown objective {objective!r}")
 
 
 @functools.partial(jax.jit,
@@ -151,16 +155,17 @@ def population_fitness(inst: PackedInstance, cum: jnp.ndarray,
             return start, dec.assign
 
         starts, assigns = jax.vmap(_decode)(prio, assign)
-        carb = ops.population_carbon(inst, starts, assigns, cum)
-        pen = VIOLATION_PENALTY * jax.vmap(
-            lambda s, a: total_violations(inst, s, a, deadline)
-        )(starts, assigns).astype(jnp.float32)
-        if objective == "carbon":
-            return carb + pen
-        if objective == "energy":
-            en = jax.vmap(lambda a: energy(inst, a))(assigns)
-            return en + ENERGY_CARBON_TIEBREAK * carb + pen
-        raise ValueError(f"unknown objective {objective!r}")
+        with scope("objectives"):
+            carb = ops.population_carbon(inst, starts, assigns, cum)
+            pen = VIOLATION_PENALTY * jax.vmap(
+                lambda s, a: total_violations(inst, s, a, deadline)
+            )(starts, assigns).astype(jnp.float32)
+            if objective == "carbon":
+                return carb + pen
+            if objective == "energy":
+                en = jax.vmap(lambda a: energy(inst, a))(assigns)
+                return en + ENERGY_CARBON_TIEBREAK * carb + pen
+            raise ValueError(f"unknown objective {objective!r}")
     return jax.vmap(lambda p, a: fitness_fn(
         inst, cum, deadline, p, a, objective, machine_rule, sweeps,
         frozen=frozen, table=table))(prio, assign)

@@ -29,8 +29,6 @@ from repro.models.common import SHAPES
 
 # Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
 # Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM).
-# A device that is not listed has no roofline: ``achieved_vs_roofline``
-# raises rather than divide its times into another chip's peaks.
 DEVICE_PEAKS = {
     "TPU v5 lite": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
 }
@@ -142,44 +140,6 @@ def model_flops(arch: str, shape: str) -> float:
         return 6.0 * n * sc.batch * sc.seq
     tokens = sc.batch * (sc.seq if sc.kind == "prefill" else 1)
     return 2.0 * n * tokens
-
-
-def achieved_vs_roofline(cost: dict, host_warm_s: float,
-                         device_kind: str) -> dict:
-    """Host-timed achieved vs roofline for one jitted program on a device.
-
-    ``cost`` is :func:`repro.launch.hlo_analysis.cost_dict` of the compiled
-    program; ``host_warm_s`` its warm time on the host's clock around a
-    synced call on a device of kind ``device_kind``, whose published
-    peaks (:data:`DEVICE_PEAKS`) give the bound.  The host time holds
-    dispatch and sync overhead as well as device time, so the
-    ``host_timed_*`` fields are lower bounds on what the device achieved,
-    not device metrics; a device's own share needs kernel time from a
-    profiler trace.  A device kind with no published peaks raises
-    ``ValueError``: there is no roofline to compare against.
-    """
-    peaks = DEVICE_PEAKS.get(device_kind)
-    if peaks is None:
-        raise ValueError(f"no published peaks for device kind "
-                         f"{device_kind!r} (known: {sorted(DEVICE_PEAKS)})")
-    flops = float(cost.get("flops", 0.0))
-    bytes_ = float(cost.get("bytes", 0.0))
-    host_warm_s = max(float(host_warm_s), 1e-12)
-    compute_s = flops / peaks["flops_per_s"]
-    memory_s = bytes_ / peaks["hbm_bytes_per_s"]
-    bound_s = max(compute_s, memory_s)
-    return {
-        "device_kind": device_kind,
-        "hlo_flops": flops,
-        "hlo_bytes": bytes_,
-        "host_timed_flops_per_s": flops / host_warm_s,
-        "host_timed_bytes_per_s": bytes_ / host_warm_s,
-        "roofline_compute_s": compute_s,
-        "roofline_memory_s": memory_s,
-        "roofline_bound_s": bound_s,
-        "host_timed_roofline_frac": bound_s / host_warm_s,
-        "dominant": "compute" if compute_s >= memory_s else "memory",
-    }
 
 
 def analyze(rec: dict) -> dict | None:

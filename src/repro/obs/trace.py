@@ -33,7 +33,8 @@ name                  ph    meaning
 ``queue_len``         C     jobs waiting for a lane per tick
 ``forecast_resolve``  i     MPC/forecast re-quantile boundary
 ``xla:<name>``        X     wall-clock span of one jitted call
-                            (args.first_call marks the compile)
+                            (args.first_call marks the compile); also a
+                            host event in a running profiler's trace
 ====================  ====  =====================================================
 
 Enable globally with ``REPRO_TRACE=1`` (checked on every
@@ -99,7 +100,9 @@ class Tracer:
     def timed(self, name: str, fn: Callable, *args: Any, **kwargs: Any):
         """Call ``fn`` and record its wall-clock span, blocking on the result
         so the span covers device execution (values are unchanged —
-        ``block_until_ready`` is an identity on the data).
+        ``block_until_ready`` is an identity on the data).  The span also
+        lands in a running profiler's trace, as a host event of the same
+        name, on the clock of the device's ops.
 
         The first call per ``name`` is flagged ``first_call=True`` — with
         jitted callees that is the compile+execute span; later calls are
@@ -110,8 +113,9 @@ class Tracer:
         first = name not in self._first_calls
         self._first_calls.add(name)
         t0 = self._clock()
-        out = fn(*args, **kwargs)
-        jax.block_until_ready(out)
+        with jax.profiler.TraceAnnotation(f"xla:{name}"):
+            out = fn(*args, **kwargs)
+            jax.block_until_ready(out)
         self.wall_span(f"xla:{name}", self._clock() - t0, first_call=first)
         return out
 

@@ -418,10 +418,11 @@ class StreamEngine:
         mfree0 = (self.mfree if self.shared_fleet
                   else jnp.zeros((self.M,), jnp.int32))
         t0 = time.perf_counter()
-        cp, budget, obj, complete = _admission_eval(
-            inst, self.cum, jnp.float32(self.stretch), jnp.int32(t), mfree0,
-            n_epochs=self.E, machine_rule=self.machine_rule)
-        complete = bool(complete)      # host sync: the admission solve ran
+        with jax.profiler.TraceAnnotation("stream.admission"):
+            cp, budget, obj, complete = _admission_eval(
+                inst, self.cum, jnp.float32(self.stretch), jnp.int32(t),
+                mfree0, n_epochs=self.E, machine_rule=self.machine_rule)
+            complete = bool(complete)  # host sync: the admission solve ran
         self._observe_wall("admission_wall_s", time.perf_counter() - t0)
         if not complete:
             # Too late even greedily: reject instead of wedging the lane.
@@ -451,36 +452,38 @@ class StreamEngine:
 
     def _finish(self, lane: int, sj: StreamJob,
                 truncated: bool = False) -> None:
-        self.pool.evict(lane)
-        row = jax.tree.map(lambda x: x[lane], self.lstate)
-        obj, viol = _eval_schedule(sj.inst, row.start, row.assign, self.cum)
-        if self.validate_evictions and int(viol) != 0:
-            raise AssertionError(
-                f"evicted job rid={sj.rid} has an infeasible schedule "
-                f"(violation mass {int(viol)})")
-        if self.shared_fleet and self.validate_evictions:
-            self._check_fleet_overlap(sj, np.asarray(row.start),
-                                      np.asarray(row.assign))
-        sj.completed = int(self._comp[lane])
-        sj.carbon = float(obj.carbon)
-        sj.energy = float(obj.energy)
-        sj.start = np.asarray(row.start)
-        sj.assign = np.asarray(row.assign)
-        sj.finished = True
-        sj.truncated = bool(truncated)
-        self.metrics.counter("jobs_completed").inc()
-        if truncated:
-            self.metrics.counter("jobs_truncated").inc()
-        self.metrics.histogram("carbon_savings_pct").observe(
-            100.0 * sj.carbon_savings)
-        if self.tracer.enabled:
-            self.tracer.span(f"job:{sj.rid}", sj.admitted, sj.completed,
-                             lane=lane, rid=sj.rid,
-                             carbon_g=round(sj.carbon, 3),
-                             greedy_carbon_g=round(sj.greedy_carbon, 3),
-                             savings_pct=round(100 * sj.carbon_savings, 2))
-            self.tracer.instant("evict", sj.completed, rid=sj.rid, lane=lane,
-                                truncated=sj.truncated)
+        with jax.profiler.TraceAnnotation("stream.eviction"):
+            self.pool.evict(lane)
+            row = jax.tree.map(lambda x: x[lane], self.lstate)
+            obj, viol = _eval_schedule(sj.inst, row.start, row.assign,
+                                       self.cum)
+            if self.validate_evictions and int(viol) != 0:
+                raise AssertionError(
+                    f"evicted job rid={sj.rid} has an infeasible schedule "
+                    f"(violation mass {int(viol)})")
+            if self.shared_fleet and self.validate_evictions:
+                self._check_fleet_overlap(sj, np.asarray(row.start),
+                                          np.asarray(row.assign))
+            sj.completed = int(self._comp[lane])
+            sj.carbon = float(obj.carbon)
+            sj.energy = float(obj.energy)
+            sj.start = np.asarray(row.start)
+            sj.assign = np.asarray(row.assign)
+            sj.finished = True
+            sj.truncated = bool(truncated)
+            self.metrics.counter("jobs_completed").inc()
+            if truncated:
+                self.metrics.counter("jobs_truncated").inc()
+            self.metrics.histogram("carbon_savings_pct").observe(
+                100.0 * sj.carbon_savings)
+            if self.tracer.enabled:
+                self.tracer.span(f"job:{sj.rid}", sj.admitted, sj.completed,
+                                 lane=lane, rid=sj.rid,
+                                 carbon_g=round(sj.carbon, 3),
+                                 greedy_carbon_g=round(sj.greedy_carbon, 3),
+                                 savings_pct=round(100 * sj.carbon_savings, 2))
+                self.tracer.instant("evict", sj.completed, rid=sj.rid,
+                                    lane=lane, truncated=sj.truncated)
 
     def _check_fleet_overlap(self, sj: StreamJob, start: np.ndarray,
                              assign: np.ndarray) -> None:
@@ -643,17 +646,18 @@ class StreamEngine:
             if self.tracer.enabled:
                 self._trace_tick(t, queue)
             t0 = time.perf_counter()
-            if self.shared_fleet:
-                self.lstate, self.mfree, done, comp = _pool_tick_shared(
-                    self.pool_inst, self.cp, self.lstate, self.mfree,
-                    self.dirty, self.budget, jnp.int32(t),
-                    self._lane_order(), machine_rule=self.machine_rule)
-            else:
-                self.lstate, self.mfree, done, comp = _pool_tick(
-                    self.pool_inst, self.cp, self.lstate, self.mfree,
-                    self.dirty, self.budget, jnp.int32(t),
-                    machine_rule=self.machine_rule)
-            self._done, self._comp = np.asarray(done), np.asarray(comp)
+            with jax.profiler.TraceAnnotation("stream.tick"):
+                if self.shared_fleet:
+                    self.lstate, self.mfree, done, comp = _pool_tick_shared(
+                        self.pool_inst, self.cp, self.lstate, self.mfree,
+                        self.dirty, self.budget, jnp.int32(t),
+                        self._lane_order(), machine_rule=self.machine_rule)
+                else:
+                    self.lstate, self.mfree, done, comp = _pool_tick(
+                        self.pool_inst, self.cp, self.lstate, self.mfree,
+                        self.dirty, self.budget, jnp.int32(t),
+                        machine_rule=self.machine_rule)
+                self._done, self._comp = np.asarray(done), np.asarray(comp)
             self._observe_wall("tick_wall_s", time.perf_counter() - t0)
             self.metrics.counter("ticks").inc()
             if self._dirty_host[t]:

@@ -6,18 +6,21 @@ Four groups:
   get-or-create registry (type conflicts are errors), reset;
 * **tracer** — event capture, the Chrome-trace export contract (the JSON
   Perfetto opens: sim epochs on one pid at 1 ms/epoch, wall spans on
-  another, metadata + counter tracks), ``REPRO_TRACE`` activation, and
-  the null tracer's zero-surface;
+  another, metadata + counter tracks), ``REPRO_TRACE`` activation, the
+  null tracer's zero-surface, the host events a profiler's trace gets
+  (engine phases, ``xla:`` spans), and the bound's stage scopes;
 * **bit-exactness** — the subsystem's hard contract: telemetry ON must
   not change a single computed value.  Property-tested over DAG families
   x fleets x both machine rules by running the same stream twice;
 * **harness** — fake-clock BenchTimer (cold/warm split is arithmetic,
   locked without real timing), perf-gate verdict logic on fake probes
   (regression / pass / fingerprint-skip / no-baseline skip), provenance
-  checks, and the roofline arithmetic (device-keyed peaks).
+  checks.
 """
 import dataclasses
+import glob
 import json
+import os
 
 import numpy as np
 import pytest
@@ -167,6 +170,55 @@ def test_traced_xla_call_passthrough_and_capture():
         assert [e["name"] for e in tr.events] == ["xla:f"]
     finally:
         set_tracer(None)
+
+
+def _host_event_names(logdir) -> set:
+    from jax.profiler import ProfileData
+    [path] = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                       recursive=True)
+    return {ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+def test_profiler_trace_names_engine_phases_and_xla_spans(tmp_path):
+    """A profile of a stream and of a traced jitted call holds the
+    engine's admission, tick and eviction and the call's ``xla:`` span as
+    host events, on the clock of the device's ops."""
+    import jax
+    import jax.numpy as jnp
+    jobs, powers, speeds, trace = _stream_case(11, "layered", "tiered", n=3,
+                                               arrival_step=3)
+    eng = StreamEngine(trace, powers, speeds, n_lanes=2, pad_tasks=PAD_TASKS)
+    step = jax.jit(lambda x: x + 1)
+    tr = Tracer()
+    set_tracer(tr)
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            eng.run(jobs)
+            traced_xla_call("probe", step, jnp.ones(3))
+    finally:
+        set_tracer(None)
+    assert {"stream.admission", "stream.tick", "stream.eviction",
+            "xla:probe"} <= _host_event_names(tmp_path)
+
+
+def test_scopes_name_the_bounds_stages_and_nothing_else():
+    import jax
+    import jax.numpy as jnp
+    from repro.obs.scopes import PHASES, STAGES, scope
+
+    def f(x):
+        with scope("phase2"), scope("timing_sweep"):
+            return jnp.sin(x)
+
+    text = jax.jit(f).lower(jnp.ones(3)).as_text(debug_info=True)
+    assert "phase2/timing_sweep/sin" in text
+    for name in STAGES + PHASES:
+        with scope(name):
+            pass
+    with pytest.raises(ValueError, match="not a stage"):
+        scope("decode")
 
 
 # ---------------------------------------------------------------------------
@@ -338,23 +390,6 @@ def test_check_provenance(tmp_path):
     assert check_provenance([str(tmp_path / "nope-*.json")])  # no match fails
 
 
-def test_roofline_achieved_columns():
-    from repro.launch.roofline import DEVICE_PEAKS, achieved_vs_roofline
-    peaks = DEVICE_PEAKS["TPU v5 lite"]
-    cost = {"flops": 2 * peaks["flops_per_s"],
-            "bytes": peaks["hbm_bytes_per_s"] / 2}
-    out = achieved_vs_roofline(cost, host_warm_s=4.0,
-                               device_kind="TPU v5 lite")
-    assert out["device_kind"] == "TPU v5 lite"
-    assert out["roofline_compute_s"] == pytest.approx(2.0)
-    assert out["roofline_memory_s"] == pytest.approx(0.5)
-    assert out["dominant"] == "compute"
-    assert out["roofline_bound_s"] == pytest.approx(2.0)
-    assert out["host_timed_roofline_frac"] == pytest.approx(0.5)
-    assert out["host_timed_flops_per_s"] == pytest.approx(
-        peaks["flops_per_s"] / 2)
-
-
 def test_compile_cache_dir_env_wins_else_fixed_repo_path(monkeypatch,
                                                          tmp_path):
     """The environment's cache directory is left to JAX; otherwise the
@@ -377,11 +412,3 @@ def test_compile_cache_dir_env_wins_else_fixed_repo_path(monkeypatch,
             assert ".jax_cache/" in f.read().split()
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
-
-
-def test_roofline_unknown_device_kind_raises():
-    """A device with no published peaks has no roofline — never a default."""
-    from repro.launch.roofline import achieved_vs_roofline
-    with pytest.raises(ValueError, match="no published peaks"):
-        achieved_vs_roofline({"flops": 1.0, "bytes": 1.0}, host_warm_s=1.0,
-                             device_kind="cpu")
