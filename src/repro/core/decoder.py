@@ -66,19 +66,29 @@ def sgs(inst: PackedInstance, prio: jnp.ndarray,
         if assign is None:
             assign = jnp.zeros((T,), jnp.int32)
 
+        tvec = jnp.arange(T, dtype=jnp.int32)
+        mvec = jnp.arange(M, dtype=jnp.int32)
+
+        # Every read and write of task t's or machine m's entry is a select
+        # on a one-hot mask: under vmap an index by a per-candidate t or m
+        # becomes a gather or scatter, which a TPU runs element by element.
+        # Each reduction has one nonzero term, so the bits are the index's.
         def body(state, i):
             scheduled, comp, mfree, start, aout, seq = state
             pending = jnp.any(pred_real & ~scheduled[None, :], axis=1)
             ready = ~scheduled & ~pending
             t = jnp.argmax(jnp.where(ready, prio, -jnp.inf))
-            pred_comp = jnp.max(jnp.where(pred_real[t], comp, 0))
-            base = jnp.maximum(inst.arrival[t], pred_comp)
+            oh = tvec == t
+            pred_t = jnp.any(oh[:, None] & pred_real, axis=0)
+            pred_comp = jnp.max(jnp.where(pred_t, comp, 0))
+            base = jnp.maximum(jnp.sum(jnp.where(oh, inst.arrival, 0)),
+                               pred_comp)
             est_m = jnp.maximum(base, mfree)               # [M]
-            dur_t = inst.dur[t]                            # [M]
+            dur_t = jnp.sum(jnp.where(oh[:, None], inst.dur, 0), axis=0)
             fin_m = est_m + dur_t
-            ok = inst.allowed[t]
+            ok = jnp.any(oh[:, None] & inst.allowed, axis=0)
             if machine_rule == "fixed":
-                m = assign[t]
+                m = jnp.sum(jnp.where(oh, assign, 0)).astype(jnp.int32)
             elif machine_rule == "earliest_finish":
                 m = jnp.argmin(jnp.where(ok, fin_m, BIG)).astype(jnp.int32)
             else:  # min_energy
@@ -86,20 +96,20 @@ def sgs(inst: PackedInstance, prio: jnp.ndarray,
                 key = jnp.where(ok, cost * 65536.0 + fin_m.astype(jnp.float32),
                                 jnp.float32(3e38))
                 m = jnp.argmin(key).astype(jnp.int32)
-            s = est_m[m]
-            c = s + dur_t[m]
-            return (scheduled.at[t].set(True),
-                    comp.at[t].set(c),
-                    mfree.at[m].set(jnp.maximum(mfree[m], c)),
-                    start.at[t].set(s),
-                    aout.at[t].set(m),
-                    seq.at[t].set(i)), None
+            ohm = mvec == m
+            s = jnp.sum(jnp.where(ohm, est_m, 0))
+            c = s + jnp.sum(jnp.where(ohm, dur_t, 0))
+            return (scheduled | oh,
+                    jnp.where(oh, c, comp),
+                    jnp.where(ohm, jnp.maximum(mfree, c), mfree),
+                    jnp.where(oh, s, start),
+                    jnp.where(oh, m, aout),
+                    jnp.where(oh, i, seq)), None
 
         init = (jnp.zeros((T,), bool), jnp.zeros((T,), jnp.int32),
                 jnp.zeros((M,), jnp.int32), jnp.zeros((T,), jnp.int32),
                 jnp.zeros((T,), jnp.int32), jnp.zeros((T,), jnp.int32))
-        (_, _, _, start, aout, seq), _ = jax.lax.scan(
-            body, init, jnp.arange(T, dtype=jnp.int32))
+        (_, _, _, start, aout, seq), _ = jax.lax.scan(body, init, tvec)
         return DecodedSchedule(start, aout, seq)
 
 

@@ -4,6 +4,7 @@ Property tests (hypothesis) pin the feasibility invariants of the SGS
 decoder and timing sweep; the exact oracle certifies optimality on tiny
 instances (replacing the paper's CP-SAT ground truth).
 """
+import dataclasses
 from unittest import mock
 
 import numpy as np
@@ -74,6 +75,71 @@ def test_sgs_always_feasible(seed, k, n, rule):
     dec = sgs(p, prio, assign, machine_rule=rule)
     assert int(violations(p, dec.start, dec.assign)) == 0
     assert not check_feasible_np(p, dec.start, dec.assign)
+
+
+def _sgs_np(p, prio, assign, rule):
+    """Serial SGS in plain numpy, one task per step: the highest-priority
+    ready task (lowest index on ties) at its earliest start on the machine
+    the rule picks (lowest index on ties)."""
+    dur, allowed, pred, arrival, real, power = (np.asarray(a) for a in (
+        p.dur, p.allowed, p.pred, p.arrival, p.task_mask, p.power))
+    T, M = dur.shape
+    done = np.zeros(T, bool)
+    comp = np.zeros(T, np.int64)
+    mfree = np.zeros(M, np.int64)
+    start, aout, seq = (np.zeros(T, np.int64) for _ in range(3))
+    for i in range(T):
+        ready = [t for t in range(T) if not done[t] and all(
+            done[u] for u in range(T) if pred[t, u] and real[u])]
+        t = min(ready, key=lambda t: (-prio[t], t))
+        base = max([int(arrival[t])] + [int(comp[u]) for u in range(T)
+                                        if pred[t, u] and real[u]])
+        est = [max(base, int(mfree[m])) for m in range(M)]
+        ok = [m for m in range(M) if allowed[t, m]]
+        if rule == "fixed":
+            m = int(assign[t])
+        elif not ok:
+            m = 0
+        elif rule == "earliest_finish":
+            m = min(ok, key=lambda m: (est[m] + dur[t, m], m))
+        else:  # min_energy: energy, then finish, packed in one f32 key
+            m = min(ok, key=lambda m: (
+                np.float32(power[m]) * np.float32(dur[t, m])
+                * np.float32(65536) + np.float32(est[m] + dur[t, m]), m))
+        done[t] = True
+        start[t], comp[t] = est[m], est[m] + dur[t, m]
+        mfree[m] = max(mfree[m], comp[t])
+        aout[t], seq[t] = m, i
+    return start, aout, seq
+
+
+@settings(deadline=None, max_examples=25)
+@given(seed=st.integers(0, 10_000), n=st.integers(1, 4),
+       k=st.integers(1, 5), n_machines=st.integers(1, 5),
+       rule=st.sampled_from(["earliest_finish", "min_energy", "fixed"]))
+def test_sgs_equals_numpy_reference(seed, n, k, n_machines, rule):
+    """Batched SGS places every candidate bit for bit as the plain serial
+    SGS does: padded tasks and machines, restricted eligibility,
+    heterogeneous fleets, and priority ties drawn on purpose."""
+    rng = np.random.default_rng(seed)
+    inst = generate_instance(rng, n_jobs=n, k_tasks=k,
+                             n_machines=n_machines,
+                             heterogeneous=bool(seed % 2))
+    elig = tuple(tuple(tuple(sorted(rng.choice(
+        n_machines, rng.integers(1, n_machines + 1), replace=False)))
+        for _ in job.base_durations) for job in inst.jobs)
+    p = pack(dataclasses.replace(inst, allowed=elig),
+             pad_tasks=20, pad_machines=6)
+    allowed = np.asarray(p.allowed)
+    prio = rng.integers(0, 4, (8, p.T)).astype(np.float32)
+    assign = np.asarray([[rng.choice(np.flatnonzero(allowed[t]))
+                          for t in range(p.T)] for _ in range(8)], np.int32)
+    got = jax.vmap(lambda q, a: sgs(p, q, a, machine_rule=rule))(
+        jnp.asarray(prio), jnp.asarray(assign))
+    for c in range(8):
+        want = _sgs_np(p, prio[c], assign[c], rule)
+        for g, w in zip(got, want):
+            assert np.array_equal(np.asarray(g[c]), w)
 
 
 @settings(deadline=None)

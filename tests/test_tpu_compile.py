@@ -9,8 +9,9 @@ is needed — and check that the program holds the Mosaic kernel
 96-candidate x 40-task population over a 1500-epoch window and over a
 366-day trace, the stream engine's 1216-epoch gate with 96-epoch windows,
 and both again under ``vmap`` as the batched solvers and policy sweeps
-call them.  One more test compiles the bound's timing sweep and checks
-that it selects its start-cost rows without a per-candidate gather.
+call them.  Two more compile the bound's serial scans: the timing sweep
+selects its start-cost rows without a per-candidate gather, and SGS
+places its tasks with no gather or scatter at all.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -28,7 +29,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import generate_instance, pack, stack_packed
-from repro.core.decoder import sweep_table, timing_sweep
+from repro.core.decoder import sgs, sweep_table, timing_sweep
 from repro.kernels import ops
 from repro.kernels.gate_quantile import gate_quantile_stats_pallas
 from repro.kernels.schedule_eval import schedule_delta_pallas
@@ -131,3 +132,24 @@ def test_timing_sweep_selects_rows_without_gather(one_chip):
             _spec(one_chip, (B,), jnp.int32)).compile().as_text()
     assert not re.search(rf"f32\[{B},{POP},{H + 1}\]\S* gather\(", text)
     assert re.search(r" convolution\(", text)
+
+
+@pytest.mark.parametrize("rule", ["earliest_finish", "fixed"])
+def test_sgs_places_without_gather_or_scatter(one_chip, rule):
+    """Under vmap an index by each candidate's task or machine becomes a
+    gather or scatter, which a TPU runs element by element: they made up
+    nearly all of SGS's device time in the bound.  Every step reads and
+    writes through one-hot selects instead."""
+    inst = pack(generate_instance(np.random.default_rng(0), n_jobs=10,
+                                  k_tasks=4, n_machines=5), pad_tasks=TASKS)
+    B = 8
+    batch = jax.tree.map(
+        lambda a: _spec(one_chip, (B,) + a.shape[1:], a.dtype),
+        stack_packed([inst]))
+    fn = jax.vmap(jax.vmap(functools.partial(sgs, machine_rule=rule),
+                           in_axes=(None, 0, 0)))
+    text = jax.jit(fn).lower(
+        batch, _spec(one_chip, (B, POP, TASKS), jnp.float32),
+        _spec(one_chip, (B, POP, TASKS), jnp.int32)).compile().as_text()
+    assert re.search(r" while\(", text)
+    assert not re.search(r" (?:gather|scatter)\(", text)
