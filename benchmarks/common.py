@@ -356,6 +356,7 @@ def run_batch(setup: BenchSetup) -> dict:
         "baseline_energy": res.baseline.energy,
         "optimized_energy": res.optimized.energy,
         "batch": batch,
+        "cum": cum,
         "result": res,
     }
 

@@ -3,7 +3,7 @@
     python chip_smoke.py              # one chip: kernels, stream, offline bound
     python chip_smoke.py --chips 4    # four chips: the sharded structure sweep
 
-One chip runs four phases in one process, each through the entry points a
+One chip runs five phases in one process, each through the entry points a
 user calls, at the sizes users run:
 
 (a) device check — JAX must see a TPU; there is no CPU fallback;
@@ -17,7 +17,12 @@ user calls, at the sizes users run:
     equal the numpy oracle ``online_carbon_gated`` exactly;
 (d) the offline bound on the paper's batch (1000 instances of 10 jobs x
     4 tasks on 5 machines, 1500-epoch windows) via ``run_batch``, every
-    schedule validated and a sample cross-checked on the host.
+    schedule validated and a sample cross-checked on the host;
+(e) the bound on the paper's 10-server fleet (32 instances, 400 start-cost
+    rows each): every row the timing sweep selects on the device equals
+    numpy's f32 row bit for bit, and in ``run_batch``'s own solve no more
+    instances than ``bound.paper_m10`` allows end less than 1% below the
+    numpy sweep of their baseline.  Both numbers are in the result line.
 
 ``--chips 4`` runs only the shard layer: the full ``structure_sweep`` grid
 on 4 devices and on 1, compared row for row, and the device of each shard
@@ -39,6 +44,7 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
+sys.path.append(os.path.join(ROOT, "chipbench", "lib"))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -313,6 +319,81 @@ def phase_offline(say) -> None:
 
 
 # ---------------------------------------------------------------------------
+# (e) The paper's 10-server fleet: the sweep's rows and phase 2's gain.
+# ---------------------------------------------------------------------------
+
+def phase_paper_m10(say) -> dict:
+    """The bound at M=10 (400 start-cost rows per instance), two checks.
+
+    The table build: every row the sweep selects from a table built on
+    the device equals the f32 row numpy computes from the same trace, bit
+    for bit.  The solve: in ``run_batch``'s own compiled program, no more
+    of the batch's instances than the cell allows end less than its
+    ``search_gain`` below the numpy sweep of their baseline.  The second
+    sees what the first cannot: a fault in the solver's own compiled
+    program (on a TPU v5e, before the table was one array, the sweep's
+    argmins came out 0 for the first half of each 8-instance block).
+    """
+    from benchmarks.common import SA_FAST, BenchSetup, run_batch
+    from repro.core.decoder import _select_row, sweep_table
+    import reference
+
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           "paper_fjsp_m10.json")) as f:
+        cfg = json.load(f)
+    setup = BenchSetup(instances=cfg["instances"], n_machines=cfg["machines"])
+    r = run_batch(setup)
+    res, batch = r["result"], r["batch"]
+    cum = np.asarray(r["cum"])
+    B, T, M = batch.dur.shape
+    H = cum.shape[1] - 1
+
+    @jax.jit
+    def rows_of(inst, c):
+        table = sweep_table(inst, c)
+        return jax.vmap(lambda j: _select_row(table, j))(jnp.arange(T * M))
+
+    rows = np.asarray(jax.vmap(rows_of)(batch, jnp.asarray(cum)))
+    svec = np.arange(H + 1)
+    dur = np.asarray(batch.dur).reshape(B, T * M)
+    unequal = sum(int((rows[b] != cum[b][np.minimum(svec + dur[b][:, None],
+                                                    H)] - cum[b][svec]
+                       ).any(axis=1).sum()) for b in range(B))
+    say(f"paper M=10: {B} instances x {T * M} rows of {H + 1} epochs "
+        f"selected on the device; rows unlike numpy's f32 rows: {unequal}")
+
+    gains = []
+    for i in range(B):
+        pred = np.asarray(batch.pred[i])
+        tasks = reference.Tasks(
+            np.asarray(batch.dur[i], np.int64),
+            tuple(tuple(np.flatnonzero(pred[t])) for t in range(T)),
+            np.asarray(batch.arrival[i], np.int64),
+            np.asarray(batch.power[i], np.float64))
+        cum64 = cum[i].astype(np.float64)
+        base = (res.baseline.start[i], res.baseline.assign[i])
+        swept = reference.timing_sweep(tasks, *base, cum64,
+                                       int(res.deadline[i]), SA_FAST.sweeps)
+        c_swept = reference.objectives(tasks, swept, base[1], cum64)[2]
+        c_opt = reference.objectives(tasks, res.optimized.start[i],
+                                     res.optimized.assign[i], cum64)[2]
+        gains.append(1.0 - c_opt / c_swept)
+    unimproved = [i for i, g in enumerate(gains) if g < cfg["search_gain"]]
+    share = len(unimproved) / B
+    say(f"paper M=10: phase 2 against the numpy sweep of each baseline: "
+        f"least gain {100 * min(gains)}%, mean {100 * np.mean(gains)}%; "
+        f"unimproved {unimproved}, share {share} (limit "
+        f"{cfg['limits']['unimproved_share']})")
+    if unequal:
+        raise AssertionError(f"{unequal} M=10 sweep rows differ from numpy")
+    if share > cfg["limits"]["unimproved_share"]:
+        raise AssertionError(f"M=10: phase 2 left instances {unimproved} "
+                             f"unimproved, share {share}")
+    return {"m10_rows_unequal": unequal, "m10_unimproved_share": share,
+            "m10_least_gain": min(gains)}
+
+
+# ---------------------------------------------------------------------------
 # --chips 4: the shard layer.
 # ---------------------------------------------------------------------------
 
@@ -374,6 +455,8 @@ def main() -> int:
         f"cache {use_compile_cache()}")
     clock = CompileClock()
     t0 = time.perf_counter()
+    result = {"ok": True, "device": {"platform": devs[0].platform,
+                                     "kind": kind, "count": len(devs)}}
     if args.chips == 1:
         with Phase(say, clock, "(b) kernels"):
             phase_kernels(say)
@@ -381,14 +464,14 @@ def main() -> int:
             phase_stream(say)
         with Phase(say, clock, "(d) offline bound"):
             phase_offline(say)
+        with Phase(say, clock, "(e) paper M=10"):
+            result["paper_m10"] = phase_paper_m10(say)
     else:
         with Phase(say, clock, "shard layer"):
             phase_shards(say, args.chips)
     say(f"all phases passed in {time.perf_counter() - t0}s wall, "
         f"{clock.seconds}s of it compiling")
-    print(json.dumps({"ok": True, "device": {
-        "platform": devs[0].platform, "kind": kind,
-        "count": len(devs)}}), flush=True)
+    print(json.dumps(result), flush=True)
     return 0
 
 

@@ -113,8 +113,9 @@ def sgs(inst: PackedInstance, prio: jnp.ndarray,
         return DecodedSchedule(start, aout, seq)
 
 
-def sweep_table(inst: PackedInstance, cum: jnp.ndarray) -> tuple | None:
-    """Every start-cost row :func:`timing_sweep` can ask for, as three
+def sweep_table(inst: PackedInstance,
+                cum: jnp.ndarray) -> jnp.ndarray | None:
+    """Every start-cost row :func:`timing_sweep` can ask for, in three
     bf16 parts whose f32 sum is the row exactly — or ``None``, where the
     sweep gathers each row itself.
 
@@ -127,9 +128,17 @@ def sweep_table(inst: PackedInstance, cum: jnp.ndarray) -> tuple | None:
     which runs on the MXU.  Off a TPU the gather is the cheap form, and
     no table is built.
 
-    An f32 ``x`` is exactly ``b1 + b2 + b3`` with ``b1 = bf16(x)``,
-    ``b2 = bf16(x - b1)``, ``b3 = x - b1 - b2`` (eight significand bits
-    each), so both forms give the same row bit for bit.
+    The table is ``[3, T*M, H+1]``: each row's high, middle and low
+    parts, eight significand bits each, whose f32 sum is the row, so both
+    forms give the same row bit for bit.  Two properties of the chip's
+    compiler shape it (found on the paper's 10-server batch, 400 rows, on
+    a TPU v5e).  Each part is cut by :func:`_top_bits` and never rounded
+    through bf16: the compiler may keep such a round trip in f32, which
+    empties the remainder ``x - bf16(x)``.  And the three parts live in
+    one array: held as three at 400 rows, the bound's fused row selection
+    and argmin stepped over 4 instances at a time, and a v5e returned
+    argmin 0 in every window but the last of each 8-instance tile
+    (``tests/test_tpu_compile.py`` checks both properties).
     """
     if jax.default_backend() != "tpu":
         return None
@@ -142,18 +151,26 @@ def sweep_table(inst: PackedInstance, cum: jnp.ndarray) -> tuple | None:
         svec = jnp.arange(H + 1, dtype=jnp.int32)
         end = jnp.minimum(svec + inst.dur[:, :, None], H)        # [T, M, H+1]
         rows = (cum[end] - cum[svec]).reshape(T * M, H + 1)
-        b1 = rows.astype(jnp.bfloat16)
-        r1 = rows - b1.astype(jnp.float32)
-        b2 = r1.astype(jnp.bfloat16)
-        b3 = (r1 - b2.astype(jnp.float32)).astype(jnp.bfloat16)
-        return b1, b2, b3
+        high = _top_bits(rows)
+        rest = rows - high
+        mid = _top_bits(rest)
+        return jnp.stack([high, mid, rest - mid]).astype(jnp.bfloat16)
 
 
-def _select_row(table: tuple, j: jnp.ndarray) -> jnp.ndarray:
+def _top_bits(x: jnp.ndarray) -> jnp.ndarray:
+    """``x`` (f32) with the low 16 bits of its pattern cleared: its sign,
+    exponent and top eight significand bits, a bf16 value held in f32.
+    ``x - _top_bits(x)`` is exact and has at most 16 significand bits."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
+
+
+def _select_row(table: jnp.ndarray, j: jnp.ndarray) -> jnp.ndarray:
     """Row ``j`` of a :func:`sweep_table`, bit-exact: each one-hot product
     returns one bf16 part exactly in f32, and the partial sums of the
     parts are exact."""
-    onehot = (jnp.arange(table[0].shape[0]) == j).astype(jnp.bfloat16)
+    onehot = (jnp.arange(table.shape[1]) == j).astype(jnp.bfloat16)
     b1, b2, b3 = (jnp.dot(onehot, b, preferred_element_type=jnp.float32)
                   for b in table)
     return (b1 + b2) + b3
@@ -164,7 +181,7 @@ def timing_sweep(inst: PackedInstance, start: jnp.ndarray,
                  assign: jnp.ndarray, cum: jnp.ndarray,
                  deadline: jnp.ndarray, sweeps: int = 2,
                  frozen: jnp.ndarray | None = None,
-                 table: tuple | None = None) -> jnp.ndarray:
+                 table: jnp.ndarray | None = None) -> jnp.ndarray:
     """Carbon-greedy timing pass.
 
     Keeps sequencing (per-machine order and DAG order) fixed and pushes each

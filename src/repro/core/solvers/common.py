@@ -58,7 +58,7 @@ def decode_full(inst: PackedInstance, cum: jnp.ndarray, deadline: jnp.ndarray,
                 objective: str = "carbon", machine_rule: str = "fixed",
                 sweeps: int = 2,
                 frozen: jnp.ndarray | None = None,
-                table: tuple | None = None) -> ScheduleResult:
+                table: jnp.ndarray | None = None) -> ScheduleResult:
     """Candidate -> feasible schedule + objective values.
 
     ``frozen`` (optional bool [T]) marks already-executing tasks the timing
@@ -106,7 +106,7 @@ def fitness_fn(inst: PackedInstance, cum: jnp.ndarray, deadline: jnp.ndarray,
                prio: jnp.ndarray, assign: jnp.ndarray, objective: str,
                machine_rule: str, sweeps: int,
                frozen: jnp.ndarray | None = None,
-               table: tuple | None = None) -> jnp.ndarray:
+               table: jnp.ndarray | None = None) -> jnp.ndarray:
     res = decode_full(inst, cum, deadline, prio, assign,
                       objective=objective, machine_rule=machine_rule,
                       sweeps=sweeps, frozen=frozen, table=table)
@@ -119,7 +119,7 @@ def population_fitness(inst: PackedInstance, cum: jnp.ndarray,
                        machine_rule: str, sweeps: int,
                        frozen: jnp.ndarray | None = None,
                        use_kernels: bool | None = None,
-                       table: tuple | None = None) -> jnp.ndarray:
+                       table: jnp.ndarray | None = None) -> jnp.ndarray:
     """Fitness of a whole candidate population.  prio/assign [Pop, T] -> [Pop].
 
     The SA/GA hot loop: every proposal evaluation, init evaluation and

@@ -5,6 +5,7 @@ decoder and timing sweep; the exact oracle certifies optimality on tiny
 instances (replacing the paper's CP-SAT ground truth).
 """
 import dataclasses
+import functools
 from unittest import mock
 
 import numpy as np
@@ -14,7 +15,8 @@ from hypothesis import given, settings, strategies as st
 import jax
 import jax.numpy as jnp
 
-from repro.core import generate_instance, pack, synthesize, validate
+from repro.core import (generate_instance, pack, stack_packed, synthesize,
+                        validate)
 from repro.core.carbon import constant, sample_window
 from repro.core.decoder import (_select_row, sgs, sweep_table, timing_sweep,
                                 upward_rank)
@@ -186,41 +188,78 @@ def test_timing_sweep_docstring_invariants(seed, slack):
         prev = c
 
 
+@functools.partial(jax.jit, static_argnames=("tpu",))
+def _rows_and_sweeps(batch, cum, start, assign, deadline, frozen, tpu):
+    """Per instance: every row its table selects (``tpu``; its TPU path,
+    built here on the CPU by reporting a TPU backend at trace time) and
+    two sweeps of each candidate, all in one ``jax.jit`` under ``vmap``
+    over instances and candidates, as ``solve_bilevel_batch`` runs them."""
+    def one(inst, c, s, a, dl, fz):
+        table = sweep_table(inst, c)
+        assert (table is not None) == tpu
+        swept = jax.vmap(lambda s1, a1: timing_sweep(
+            inst, s1, a1, c, dl, sweeps=2, frozen=fz, table=table))(s, a)
+        if table is None:
+            return None, swept
+        return jax.vmap(lambda j: _select_row(table, j))(
+            jnp.arange(inst.T * inst.M)), swept
+    return jax.vmap(one)(batch, cum, start, assign, deadline, frozen)
+
+
+def _check_table_equals_gather(rng, insts, cum, slack, frozen=None):
+    """The table form selects every start-cost row bit for bit as the
+    gather form computes it, so whole sweeps of a population of 8
+    candidates agree exactly."""
+    batch = stack_packed(insts)
+    B, T = len(insts), insts[0].T
+    H = cum.shape[1] - 1
+    prio = jnp.asarray(rng.normal(size=(B, 8, T)), jnp.float32)
+    dec = jax.vmap(jax.vmap(sgs, in_axes=(None, 0)))(batch, prio)
+    deadline = jnp.max(jax.vmap(jax.vmap(makespan, in_axes=(None, 0, 0)))(
+        batch, dec.start, dec.assign), axis=1) + slack
+    args = (batch, cum, dec.start, dec.assign, deadline, frozen)
+    assert sweep_table(insts[0], cum[0]) is None
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        rows, got = _rows_and_sweeps(*args, tpu=True)
+    _, ref = _rows_and_sweeps(*args, tpu=False)
+    svec = np.arange(H + 1)
+    for b, p in enumerate(insts):
+        c = np.asarray(cum[b])
+        want = c[np.minimum(svec + np.asarray(p.dur).reshape(-1, 1), H)] \
+            - c[svec]
+        assert np.array_equal(np.asarray(rows[b]), want)
+    assert np.array_equal(np.asarray(got), np.asarray(ref))
+
+
 @settings(deadline=None, max_examples=25)
 @given(seed=st.integers(0, 10_000), slack=st.integers(0, 60))
 def test_timing_sweep_table_equals_gather(seed, slack):
-    """The sweep's one-hot table form (its TPU path, built here on the
-    CPU by reporting a TPU backend) selects every start-cost row bit for
-    bit as the gather form computes it, so whole sweeps agree exactly,
-    with padded and frozen tasks too."""
+    """The sweep's one-hot table form and its gather form agree bit for
+    bit (:func:`_check_table_equals_gather`), with padded and frozen
+    tasks too."""
     rng = np.random.default_rng(seed)
     inst = generate_instance(rng, n_jobs=3, k_tasks=4, n_machines=3,
                              heterogeneous=bool(seed % 2))
     p = pack(inst, pad_tasks=16)
-    cum = _trace_cum(rng)
-    H = cum.shape[0] - 1
-    svec = jnp.arange(H + 1, dtype=jnp.int32)
-    assert sweep_table(p, cum) is None
-    with mock.patch("jax.default_backend", return_value="tpu"):
-        table = sweep_table(p, cum)
-    rows = jax.vmap(lambda j: _select_row(table, j))(jnp.arange(p.T * p.M))
-    want = jnp.stack([cum[jnp.minimum(svec + p.dur[t, m], H)] - cum[svec]
-                      for t in range(p.T) for m in range(p.M)])
-    assert np.array_equal(np.asarray(rows), np.asarray(want))
-
-    prio = jnp.asarray(rng.normal(size=(8, p.T)), jnp.float32)
-    dec = jax.vmap(lambda q: sgs(p, q))(prio)
-    deadline = jnp.int32(int(jnp.max(jax.vmap(
-        lambda s, a: makespan(p, s, a))(dec.start, dec.assign))) + slack)
-    frozen = jnp.asarray(rng.random(p.T) < 0.25)
+    cum = _trace_cum(rng)[None]
+    frozen = jnp.asarray(rng.random((1, p.T)) < 0.25)
     for fz in (None, frozen):
-        sweep = lambda s, a, tb: timing_sweep(  # noqa: E731
-            p, s, a, cum, deadline, sweeps=2, frozen=fz, table=tb)
-        got = jax.vmap(lambda s, a: sweep(s, a, table))(dec.start,
-                                                          dec.assign)
-        ref = jax.vmap(lambda s, a: sweep(s, a, None))(dec.start,
-                                                         dec.assign)
-        assert np.array_equal(np.asarray(got), np.asarray(ref))
+        _check_table_equals_gather(rng, [p], cum, slack, fz)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_timing_sweep_table_equals_gather_paper_m10(seed):
+    """The same at the paper's 10-server shape: 40 tasks on 10 machines,
+    400 rows, a 1500-epoch window, two instances.  On the CPU both forms
+    are exact whatever the table's layout; what the chip's compiler does
+    with the table is held by ``tests/test_tpu_compile.py``."""
+    rng = np.random.default_rng(seed)
+    insts = [pack(generate_instance(rng, n_jobs=10, k_tasks=4,
+                                    n_machines=10), pad_tasks=40)
+             for _ in range(2)]
+    assert insts[0].T * insts[0].M == 400
+    cum = jnp.stack([_trace_cum(rng, 1500) for _ in insts])
+    _check_table_equals_gather(rng, insts, cum, 30)
 
 
 def test_upward_rank_tops_roots(rng):

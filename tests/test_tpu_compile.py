@@ -9,9 +9,11 @@ is needed — and check that the program holds the Mosaic kernel
 96-candidate x 40-task population over a 1500-epoch window and over a
 366-day trace, the stream engine's 1216-epoch gate with 96-epoch windows,
 and both again under ``vmap`` as the batched solvers and policy sweeps
-call them.  Two more compile the bound's serial scans: the timing sweep
-selects its start-cost rows without a per-candidate gather, and SGS
-places its tasks with no gather or scatter at all.
+call them.  Four more compile the bound's serial scans: the timing sweep
+selects its start-cost rows without a per-candidate gather, its table
+is one array of bf16 parts with no bf16 round trip for the chip to skip,
+the bound's row selection steps over its instances in whole 8-instance
+tiles, and SGS places its tasks with no gather or scatter at all.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -132,6 +134,96 @@ def test_timing_sweep_selects_rows_without_gather(one_chip):
             _spec(one_chip, (B,), jnp.int32)).compile().as_text()
     assert not re.search(rf"f32\[{B},{POP},{H + 1}\]\S* gather\(", text)
     assert re.search(r" convolution\(", text)
+
+
+def test_sweep_table_is_one_array_cut_by_bit_masks(one_chip):
+    """At the paper's 10-server shape (400 rows) the table is one
+    ``bf16[3, T*M, H+1]`` array per instance, its parts cut from each f32
+    row by bit masks: no convert from bf16 back to f32.  A split by
+    rounding (``x - f32(bf16(x))``) the chip ran in f32, which emptied
+    every row's low parts, and parts held as three arrays the chip read
+    wrong for half of each 8-instance block: half of the batch's phase-2
+    searches never improved."""
+    inst = pack(generate_instance(np.random.default_rng(0), n_jobs=10,
+                                  k_tasks=4, n_machines=10), pad_tasks=TASKS)
+    B, H = 32, 1500
+    batch = jax.tree.map(
+        lambda a: _spec(one_chip, (B,) + a.shape[1:], a.dtype),
+        stack_packed([inst]))
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        lowered = jax.jit(jax.vmap(sweep_table)).lower(
+            batch, _spec(one_chip, (B, H + 1), jnp.float32))
+    assert lowered.out_info.shape == (B, 3, TASKS * 10, H + 1)
+    ops = {}
+    for name, dtype, opcode, args in re.findall(
+            r"%(\S+) = (\w+)\[[^\]]*\]\S* ([\w-]+)\(([^)]*)\)",
+            lowered.compile().as_text()):
+        ops[name] = (dtype, opcode, re.findall(r"%(\S+?)(?:[,\s]|$)", args))
+    assert any(d == "u32" and op == "and" for d, op, _ in ops.values())
+    round_trips = [name for name, (d, op, args) in ops.items()
+                   if (d, op) == ("f32", "convert") and any(
+                       ops.get(a, ("", "", []))[:2] == ("bf16", "convert")
+                       for a in args)]
+    assert not round_trips
+
+
+def _called(text: str) -> dict:
+    """Each computation of a compiled HLO text: name -> its body."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(", line)
+        if head:
+            cur = comps[head.group(1)] = []
+        elif cur is not None:
+            cur.append(line)
+    return {name: "\n".join(body) for name, body in comps.items()}
+
+
+@pytest.mark.parametrize("machines", [5, 10])
+def test_bound_sweep_argmin_steps_whole_tiles(one_chip, machines):
+    """The bound's own program on the paper's fleets (32 instances, 200
+    or 400 start-cost rows): the fusion that selects each candidate's row
+    and takes its argmin (outputs ``s32[32, 96]``, tiled 8 instances x
+    128 candidates) steps over the instances 8 at a time or in multiples
+    of 8.  On a TPU v5e such a fusion stepping 4 or 2 at a time returned
+    wrong argmins, mostly 0, for every window but the last of each
+    8-instance tile: half of the 10-server batch's phase-2 searches never
+    improved.  Three separate table parts compiled that way at 400 rows;
+    one ``[3, T*M, H+1]`` array compiles to whole tiles."""
+    from repro.core.solvers import solve_bilevel_batch
+    from repro.core.solvers.annealing import SAConfig
+
+    inst = pack(generate_instance(np.random.default_rng(0), n_jobs=10,
+                                  k_tasks=4, n_machines=machines),
+                pad_tasks=TASKS)
+    B, H = 32, 1500
+    batch = jax.tree.map(
+        lambda a: _spec(one_chip, (B,) + a.shape[1:], a.dtype),
+        stack_packed([inst]))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), B))
+    sa = SAConfig(pop=POP, iters=150, sweeps=2)
+    fn = functools.partial(solve_bilevel_batch, objective="carbon",
+                           stretch=1.0, cfg1=sa, cfg2=sa)
+    with mock.patch("jax.default_backend", return_value="tpu"), \
+            mock.patch.object(ops, "on_tpu", return_value=True):
+        text = jax.jit(fn).lower(
+            batch, _spec(one_chip, (B, H + 1), jnp.float32),
+            _spec(one_chip, keys.shape, keys.dtype)).compile().as_text()
+    comps = _called(text)
+
+    def holds_product(name, seen=()):
+        body = comps.get(name, "")
+        return " convolution(" in body or any(
+            holds_product(c, seen + (name,))
+            for c in re.findall(r"calls=%([\w.\-]+)", body) if c not in seen)
+
+    steps = [int(re.search(r'"kernel_window_bounds":\["(\d+)"', ln).group(1))
+             for ln in text.splitlines()
+             if "timing_sweep/while" in ln and " fusion(" in ln
+             and re.search(rf"= \(.*s32\[{B},{POP}\]", ln.split(" fusion(")[0])
+             and holds_product(re.search(r"calls=%([\w.\-]+)", ln).group(1))]
+    assert steps
+    assert all(step % 8 == 0 for step in steps), steps
 
 
 @pytest.mark.parametrize("rule", ["earliest_finish", "fixed"])
