@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.instance import PackedInstance
-from repro.core.objectives import task_durations
 from repro.obs.scopes import scope
 
 BIG = jnp.int32(1 << 28)
@@ -207,37 +206,52 @@ def timing_sweep(inst: PackedInstance, start: jnp.ndarray,
     with scope("timing_sweep"):
         T, M = inst.T, inst.M
         H = cum.shape[0] - 1
-        d = task_durations(inst, assign)
         real = inst.task_mask
         sweepable = real if frozen is None else real & ~frozen
+        tvec = jnp.arange(T, dtype=jnp.int32)
+        mvec = jnp.arange(M, dtype=jnp.int32)
         svec = jnp.arange(H + 1, dtype=jnp.int32)
+        # As in :func:`sgs`, every read and write of a task's or machine's
+        # entry is a select on a one-hot mask, never an index by the
+        # candidate's own t or assignment: under vmap that is a gather or
+        # scatter, which a TPU runs element by element.  Each reduction has
+        # one nonzero term, so the bits are the index's.  Task t's machine
+        # successors are read from ``assign``, with no [T, T] array.
+        d = jnp.sum(jnp.where(assign[:, None] == mvec, inst.dur, 0), axis=1)
         if table is None:
             table = sweep_table(inst, cum)
-        same_m = (assign[:, None] == assign[None, :]) & real[None, :]
         succ = inst.pred.T & real[None, :]          # succ[t, v]: t -> v edge
 
         def one_sweep(start):
             # Freeze the sequence key for this sweep: (start, idx) descending.
-            key = start * jnp.int32(T) + jnp.arange(T, dtype=jnp.int32)
+            key = start * jnp.int32(T) + tvec
             order = jnp.argsort(-jnp.where(real, key, -BIG))  # pads last
 
             def body(start_cur, t):
-                dt = d[t]
-                succ_cap = jnp.min(jnp.where(succ[t], start_cur, BIG))
-                after = same_m[t] & (key > key[t])
+                oh = tvec == t
+                dt = jnp.sum(jnp.where(oh, d, 0))
+                a_t = jnp.sum(jnp.where(oh, assign, 0))
+                lo = jnp.sum(jnp.where(oh, start_cur, 0))
+                succ_t = jnp.any(oh[:, None] & succ, axis=0)
+                succ_cap = jnp.min(jnp.where(succ_t, start_cur, BIG))
+                after = ((assign == a_t) & real
+                         & (key > jnp.sum(jnp.where(oh, key, 0))))
                 mnext_cap = jnp.min(jnp.where(after, start_cur, BIG))
                 hi = jnp.minimum(jnp.minimum(succ_cap, mnext_cap),
                                  deadline.astype(jnp.int32)) - dt
-                lo = start_cur[t]
                 if table is None:
                     cost = cum[jnp.minimum(svec + dt, H)] - cum[svec]
                 else:
-                    cost = _select_row(table, t * M + assign[t])
+                    cost = _select_row(table, t * M + a_t)
                 cost = jnp.where((svec >= lo) & (svec <= hi), cost, jnp.inf)
+                # The barrier keeps the argmin out of the fusion of the row
+                # product and the mask: a v5e returned argmin 0 from such a
+                # fusion stepped over fewer than 8 instances (sweep_table).
+                # Alone, the argmin steps whole tiles.
+                cost = jax.lax.optimization_barrier(cost)
                 s_star = jnp.argmin(cost).astype(jnp.int32)
-                movable = sweepable[t] & (hi >= lo)
-                new_s = jnp.where(movable, s_star, start_cur[t])
-                return start_cur.at[t].set(new_s), None
+                movable = jnp.any(oh & sweepable) & (hi >= lo)
+                return jnp.where(oh & movable, s_star, start_cur), None
 
             start, _ = jax.lax.scan(body, start, order)
             return start

@@ -188,17 +188,20 @@ def test_timing_sweep_docstring_invariants(seed, slack):
         prev = c
 
 
-@functools.partial(jax.jit, static_argnames=("tpu",))
-def _rows_and_sweeps(batch, cum, start, assign, deadline, frozen, tpu):
+@functools.partial(jax.jit, static_argnames=("tpu", "sweeps"))
+def _rows_and_sweeps(batch, cum, start, assign, deadline, frozen, tpu,
+                     sweeps=2):
     """Per instance: every row its table selects (``tpu``; its TPU path,
     built here on the CPU by reporting a TPU backend at trace time) and
-    two sweeps of each candidate, all in one ``jax.jit`` under ``vmap``
-    over instances and candidates, as ``solve_bilevel_batch`` runs them."""
+    ``sweeps`` sweeps of each candidate, all in one ``jax.jit`` under
+    ``vmap`` over instances and candidates, as ``solve_bilevel_batch``
+    runs them."""
     def one(inst, c, s, a, dl, fz):
         table = sweep_table(inst, c)
         assert (table is not None) == tpu
         swept = jax.vmap(lambda s1, a1: timing_sweep(
-            inst, s1, a1, c, dl, sweeps=2, frozen=fz, table=table))(s, a)
+            inst, s1, a1, c, dl, sweeps=sweeps, frozen=fz,
+            table=table))(s, a)
         if table is None:
             return None, swept
         return jax.vmap(lambda j: _select_row(table, j))(
@@ -260,6 +263,75 @@ def test_timing_sweep_table_equals_gather_paper_m10(seed):
     assert insts[0].T * insts[0].M == 400
     cum = jnp.stack([_trace_cum(rng, 1500) for _ in insts])
     _check_table_equals_gather(rng, insts, cum, 30)
+
+
+def _timing_sweep_np(dur, pred, real, start, assign, cum, deadline, sweeps,
+                     frozen):
+    """A plain serial timing sweep, written apart from the jnp one.  Each
+    sweep takes the real tasks by descending ``(start, index)`` as at its
+    beginning; each task not pinned moves, within its window, to the
+    start of least ``cum[min(s + d, H)] - cum[s]``, earliest among equals.
+    The window starts at its start and ends where it would overrun its
+    first real successor, the next real task on its machine, or the
+    deadline."""
+    T, H = len(start), len(cum) - 1
+    start = np.array(start, np.int64)
+    d = dur[np.arange(T), assign]
+    for _ in range(sweeps):
+        key = start * T + np.arange(T)
+        for t in sorted(np.flatnonzero(real), key=lambda t: -key[t]):
+            caps = [deadline]
+            for v in np.flatnonzero(real):
+                if pred[v, t] or (assign[v] == assign[t] and key[v] > key[t]):
+                    caps.append(start[v])
+            lo, hi = start[t], min(caps) - d[t]
+            if frozen[t] or hi < lo:
+                continue
+            assert hi <= H, "the window must lie inside the trace"
+            s = np.arange(lo, hi + 1)
+            start[t] = lo + np.argmin(cum[np.minimum(s + d[t], H)] - cum[s])
+    return start
+
+
+@settings(deadline=None, max_examples=20)
+@given(seed=st.integers(0, 10_000), slack=st.integers(0, 60))
+def test_timing_sweep_equals_numpy_sweep(seed, slack):
+    """The batched sweep (two instances of a heterogeneous fleet with
+    padded tasks, 6 candidates each, under vmap as the solvers run it)
+    returns the plain serial sweep's starts bit for bit, in both row
+    sources (the table on a TPU, the gather elsewhere), for one and two
+    sweeps, with and without pinned tasks."""
+    rng = np.random.default_rng(seed)
+    insts = [pack(generate_instance(rng, n_jobs=3, k_tasks=4, n_machines=4,
+                                    heterogeneous=True), pad_tasks=16)
+             for _ in range(2)]
+    batch = stack_packed(insts)
+    cum = jnp.stack([_trace_cum(rng) for _ in insts])
+    prio = jnp.asarray(rng.normal(size=(2, 6, 16)), jnp.float32)
+    dec = jax.vmap(jax.vmap(sgs, in_axes=(None, 0)))(batch, prio)
+    deadline = jnp.max(jax.vmap(jax.vmap(makespan, in_axes=(None, 0, 0)))(
+        batch, dec.start, dec.assign), axis=1) + slack
+    pinned = jnp.asarray(rng.random((2, 16)) < 0.3)
+    for sweeps in (1, 2):
+        for frozen in (None, pinned):
+            for tpu in (False, True):
+                with mock.patch("jax.default_backend",
+                                return_value="tpu" if tpu else "cpu"):
+                    _, got = _rows_and_sweeps(
+                        batch, cum, dec.start, dec.assign, deadline,
+                        frozen, sweeps=sweeps, tpu=tpu)
+                for b, p in enumerate(insts):
+                    fz = (np.zeros(16, bool) if frozen is None
+                          else np.asarray(frozen[b]))
+                    for c in range(6):
+                        want = _timing_sweep_np(
+                            np.asarray(p.dur), np.asarray(p.pred),
+                            np.asarray(p.task_mask),
+                            np.asarray(dec.start[b, c]),
+                            np.asarray(dec.assign[b, c]), np.asarray(cum[b]),
+                            int(deadline[b]), sweeps, fz)
+                        assert np.array_equal(np.asarray(got[b, c]), want), (
+                            sweeps, frozen is not None, tpu, b, c)
 
 
 def test_upward_rank_tops_roots(rng):

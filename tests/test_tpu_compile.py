@@ -9,11 +9,12 @@ is needed — and check that the program holds the Mosaic kernel
 96-candidate x 40-task population over a 1500-epoch window and over a
 366-day trace, the stream engine's 1216-epoch gate with 96-epoch windows,
 and both again under ``vmap`` as the batched solvers and policy sweeps
-call them.  Four more compile the bound's serial scans: the timing sweep
-selects its start-cost rows without a per-candidate gather, its table
-is one array of bf16 parts with no bf16 round trip for the chip to skip,
-the bound's row selection steps over its instances in whole 8-instance
-tiles, and SGS places its tasks with no gather or scatter at all.
+call them.  Five more compile the bound's serial scans: the timing sweep
+selects its start-cost rows without a per-candidate gather, its scan
+reads and writes with no gather or scatter at all, its table is one
+array of bf16 parts with no bf16 round trip for the chip to skip, the
+bound's sweep writes its argmins in whole 8-instance tiles, and SGS
+places its tasks with no gather or scatter at all.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -136,6 +137,45 @@ def test_timing_sweep_selects_rows_without_gather(one_chip):
     assert re.search(r" convolution\(", text)
 
 
+@pytest.mark.parametrize("frozen", [False, True], ids=["free", "frozen"])
+@pytest.mark.parametrize("machines", [5, 10])
+def test_timing_sweep_scan_without_gather_or_scatter(one_chip, machines,
+                                                     frozen):
+    """Under vmap an index by each candidate's own task (its duration,
+    successors, machine, key and start) becomes a gather and the write of
+    its new start a scatter, which a TPU runs element by element: 14
+    gathers and 2 scatters in the loop made up most of the sweep's time in
+    the bound.  Every step reads and writes through one-hot selects
+    instead, on the paper's fleets and with pinned tasks too."""
+    inst = pack(generate_instance(np.random.default_rng(0), n_jobs=10,
+                                  k_tasks=4, n_machines=machines),
+                pad_tasks=TASKS)
+    B, H = 32, 1500
+    batch = jax.tree.map(
+        lambda a: _spec(one_chip, (B,) + a.shape[1:], a.dtype),
+        stack_packed([inst]))
+
+    def sweep(inst, start, assign, cum, deadline, fz):
+        table = sweep_table(inst, cum)
+        return jax.vmap(lambda s, a: timing_sweep(
+            inst, s, a, cum, deadline, 2, frozen=fz if frozen else None,
+            table=table))(start, assign)
+
+    with mock.patch("jax.default_backend", return_value="tpu"):
+        text = jax.jit(jax.vmap(sweep)).lower(
+            batch, _spec(one_chip, (B, POP, TASKS), jnp.int32),
+            _spec(one_chip, (B, POP, TASKS), jnp.int32),
+            _spec(one_chip, (B, H + 1), jnp.float32),
+            _spec(one_chip, (B,), jnp.int32),
+            _spec(one_chip, (B, TASKS), jnp.bool_)).compile().as_text()
+    comps = _called(text)
+    loop = [ln for ln in text.splitlines() if "timing_sweep/while" in ln]
+    assert any(_holds(comps, ln, r" convolution\(") for ln in loop)
+    indexed = [ln.split(" = ")[0].strip() for ln in loop
+               if _holds(comps, ln, r" (?:gather|scatter)\(")]
+    assert not indexed, indexed
+
+
 def test_sweep_table_is_one_array_cut_by_bit_masks(one_chip):
     """At the paper's 10-server shape (400 rows) the table is one
     ``bf16[3, T*M, H+1]`` array per instance, its parts cut from each f32
@@ -179,17 +219,29 @@ def _called(text: str) -> dict:
     return {name: "\n".join(body) for name, body in comps.items()}
 
 
+def _holds(comps: dict, text: str, pattern: str, seen: tuple = ()) -> bool:
+    """Whether HLO text (a line, or a computation's body), or any
+    computation it calls, transitively, matches ``pattern``."""
+    return bool(re.search(pattern, text)) or any(
+        _holds(comps, comps.get(c, ""), pattern, seen + (c,))
+        for c in re.findall(r"calls=%([\w.\-]+)", text) if c not in seen)
+
+
 @pytest.mark.parametrize("machines", [5, 10])
 def test_bound_sweep_argmin_steps_whole_tiles(one_chip, machines):
     """The bound's own program on the paper's fleets (32 instances, 200
-    or 400 start-cost rows): the fusion that selects each candidate's row
-    and takes its argmin (outputs ``s32[32, 96]``, tiled 8 instances x
-    128 candidates) steps over the instances 8 at a time or in multiples
-    of 8.  On a TPU v5e such a fusion stepping 4 or 2 at a time returned
-    wrong argmins, mostly 0, for every window but the last of each
-    8-instance tile: half of the 10-server batch's phase-2 searches never
-    improved.  Three separate table parts compiled that way at 400 rows;
-    one ``[3, T*M, H+1]`` array compiles to whole tiles."""
+    or 400 start-cost rows): the sweep's loop holds the argmin of each
+    candidate's start-cost row (outputs ``s32[32, 96]``, tiled 8
+    instances x 128 candidates), and every fusion of the loop that writes
+    such an output steps over the instances 8 at a time or in multiples
+    of 8 (its first iterated dimension, which its windows show to be the
+    instances).  On a TPU
+    v5e a fusion of the row product and its argmin stepping 4 or 2 at a
+    time returned wrong argmins, mostly 0, for every window but the last
+    of each 8-instance tile: half of the 10-server batch's phase-2
+    searches never improved.  The sweep keeps the argmin out of the
+    product's fusion, and this holds the fusion it lands in to whole
+    tiles too."""
     from repro.core.solvers import solve_bilevel_batch
     from repro.core.solvers.annealing import SAConfig
 
@@ -210,20 +262,29 @@ def test_bound_sweep_argmin_steps_whole_tiles(one_chip, machines):
             batch, _spec(one_chip, (B, H + 1), jnp.float32),
             _spec(one_chip, keys.shape, keys.dtype)).compile().as_text()
     comps = _called(text)
-
-    def holds_product(name, seen=()):
-        body = comps.get(name, "")
-        return " convolution(" in body or any(
-            holds_product(c, seen + (name,))
-            for c in re.findall(r"calls=%([\w.\-]+)", body) if c not in seen)
-
-    steps = [int(re.search(r'"kernel_window_bounds":\["(\d+)"', ln).group(1))
-             for ln in text.splitlines()
-             if "timing_sweep/while" in ln and " fusion(" in ln
-             and re.search(rf"= \(.*s32\[{B},{POP}\]", ln.split(" fusion(")[0])
-             and holds_product(re.search(r"calls=%([\w.\-]+)", ln).group(1))]
+    writes = [ln for ln in text.splitlines()
+              if "timing_sweep/while" in ln and " fusion(" in ln
+              and re.search(rf"= \(?.*s32\[{B},{POP}\]",
+                            ln.split(" fusion(")[0])]
+    # An argmin: a reduce whose outputs end in the indices, s32[B, POP].
+    assert any(_holds(comps, ln, rf"s32\[{B},{POP}\]\S*\) reduce\(")
+               for ln in writes)
+    steps = {}
+    for ln in writes:
+        name = ln.split(" = ")[0].strip()
+        iters = [int(n) for n in re.search(
+            r'"iteration_bounds":\[([^]]*)\]', ln).group(1)
+            .replace('"', "").split(",")]
+        # The first iterated dimension must be the instances: each window
+        # times its step count covers B instances, or B // 8 tiles of 8.
+        for kind in ("kernel", "output"):
+            window = re.search(rf'"{kind}_window_bounds":\["(\d+)"', ln)
+            if window:
+                covers = int(window.group(1)) * iters[0]
+                assert covers in (B, B // 8), (name, kind, covers)
+        steps[name] = B // iters[0] if B % iters[0] == 0 else 0
     assert steps
-    assert all(step % 8 == 0 for step in steps), steps
+    assert all(step % 8 == 0 and step for step in steps.values()), steps
 
 
 @pytest.mark.parametrize("rule", ["earliest_finish", "fixed"])
