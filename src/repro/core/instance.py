@@ -109,7 +109,7 @@ class PackedInstance(NamedTuple):
     Together the two axes let :func:`repro.scenarios.batching.pack_aligned`
     stack *mixed-shape* instances (different DAG families, task counts and
     fleet sizes) into one ``[B, ...]`` batch that ``online_jax``/``rolling``
-    and the SA/GA solvers vmap over unchanged.
+    and the SA solver vmap over unchanged.
     """
 
     dur: jnp.ndarray        # int32 [T, M]

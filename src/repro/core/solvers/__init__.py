@@ -1,6 +1,5 @@
 from repro.core.solvers.common import ScheduleResult, fitness_fn, decode_full
 from repro.core.solvers.annealing import solve_sa
-from repro.core.solvers.genetic import solve_ga
 from repro.core.solvers.bilevel import BilevelResult, solve_bilevel, solve_bilevel_batch
 from repro.core.solvers.online import online_carbon_gated, online_greedy
 from repro.core.solvers.online_jax import (OnlineSchedule, SweepResult,
@@ -11,7 +10,7 @@ from repro.core.solvers.rolling import (MPCConfig, MPCResult, solve_mpc,
                                         solve_mpc_batch)
 
 __all__ = [
-    "ScheduleResult", "fitness_fn", "decode_full", "solve_sa", "solve_ga",
+    "ScheduleResult", "fitness_fn", "decode_full", "solve_sa",
     "BilevelResult", "solve_bilevel", "solve_bilevel_batch",
     "online_carbon_gated", "online_greedy",
     "OnlineSchedule", "SweepResult", "online_carbon_gated_jax",

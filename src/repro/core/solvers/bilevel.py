@@ -26,7 +26,6 @@ from repro.core.decoder import sweep_table
 from repro.core.instance import PackedInstance
 from repro.core.solvers import common
 from repro.core.solvers.annealing import SAConfig, solve_sa
-from repro.core.solvers.genetic import GAConfig, solve_ga
 from repro.obs.scopes import scope
 
 NO_DEADLINE = jnp.int32(1 << 27)
@@ -42,34 +41,27 @@ class BilevelResult(NamedTuple):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("objective", "stretch", "solver", "cfg1", "cfg2",
+    jax.jit, static_argnames=("objective", "stretch", "cfg1", "cfg2",
                               "use_kernels"))
 def solve_bilevel(inst: PackedInstance, cum: jnp.ndarray, key: jax.Array,
                   objective: str = "carbon", stretch: float = 1.0,
-                  solver: str = "sa",
-                  cfg1: SAConfig | GAConfig | None = None,
-                  cfg2: SAConfig | GAConfig | None = None,
+                  cfg1: SAConfig | None = None,
+                  cfg2: SAConfig | None = None,
                   use_kernels: bool | None = None) -> BilevelResult:
-    """``use_kernels`` selects the Pallas fitness path inside both solver
-    phases (bit-exact equal to the jnp path, so the result is identical
-    either way); ``None`` defers to ``REPRO_KERNELS`` / backend default."""
-    if solver == "sa":
-        solve = solve_sa
-        cfg1 = cfg1 or SAConfig()
-        cfg2 = cfg2 or cfg1
-    elif solver == "ga":
-        solve = solve_ga
-        cfg1 = cfg1 or GAConfig()
-        cfg2 = cfg2 or cfg1
-    else:
-        raise ValueError(f"unknown solver {solver!r}")
+    """Both phases search with :func:`solve_sa`: phase 1 with ``cfg1``
+    (default :class:`SAConfig`), phase 2 with ``cfg2`` (default ``cfg1``).
+    ``use_kernels`` selects the fitness path inside both phases
+    (:func:`repro.core.solvers.common.population_fitness`); ``None`` defers
+    to ``REPRO_KERNELS`` / backend default."""
+    cfg1 = cfg1 or SAConfig()
+    cfg2 = cfg2 or cfg1
     k1, k2 = jax.random.split(key)
 
     # ---- Phase 1: makespan-only (the carbon-agnostic baseline). ----------
     with scope("phase1"):
-        p1 = solve(inst, cum, NO_DEADLINE, k1, objective="makespan",
-                   machine_rule="earliest_finish", cfg=cfg1,
-                   use_kernels=use_kernels)
+        p1 = solve_sa(inst, cum, NO_DEADLINE, k1, objective="makespan",
+                      machine_rule="earliest_finish", cfg=cfg1,
+                      use_kernels=use_kernels)
         baseline = common.decode_full(
             inst, cum, NO_DEADLINE, p1.prio, p1.assign,
             objective="makespan", machine_rule="earliest_finish", sweeps=0)
@@ -80,15 +72,15 @@ def solve_bilevel(inst: PackedInstance, cum: jnp.ndarray, key: jax.Array,
     # ---- Phase 2: carbon/energy under makespan <= S * OPT. ---------------
     # Warm start: the baseline's own (sequence, assignment) is feasible.
     with scope("phase2"):
-        p2 = solve(inst, cum, deadline, k2, objective=objective,
-                   machine_rule="fixed", cfg=cfg2,
-                   prio_init=-baseline.start.astype(jnp.float32),
-                   assign_init=baseline.assign, use_kernels=use_kernels)
+        p2 = solve_sa(inst, cum, deadline, k2, objective=objective,
+                      machine_rule="fixed", cfg=cfg2,
+                      prio_init=-baseline.start.astype(jnp.float32),
+                      assign_init=baseline.assign, use_kernels=use_kernels)
         table = sweep_table(inst, cum)
         optimized = common.decode_full(
             inst, cum, deadline, p2.prio, p2.assign,
-            objective=objective, machine_rule="fixed", sweeps=max(
-                getattr(cfg2, "sweeps", 2), 1), table=table)
+            objective=objective, machine_rule="fixed",
+            sweeps=max(cfg2.sweeps, 1), table=table)
 
         # Guard: if phase 2 somehow ended worse (it cannot, given the warm
         # start chain is kept, but belt-and-braces), fall back to the
@@ -96,7 +88,7 @@ def solve_bilevel(inst: PackedInstance, cum: jnp.ndarray, key: jax.Array,
         fallback = common.decode_full(
             inst, cum, deadline, -baseline.start.astype(jnp.float32),
             baseline.assign, objective=objective, machine_rule="fixed",
-            sweeps=max(getattr(cfg2, "sweeps", 2), 1), table=table)
+            sweeps=max(cfg2.sweeps, 1), table=table)
         key_obj = {"carbon": 4, "energy": 3}[objective]
         use_fb = (optimized[key_obj] > fallback[key_obj]) | \
             (optimized.makespan > deadline)

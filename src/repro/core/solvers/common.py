@@ -51,6 +51,31 @@ class ScheduleResult(NamedTuple):
     utilization: jnp.ndarray
 
 
+def _decode(inst: PackedInstance, cum: jnp.ndarray, deadline: jnp.ndarray,
+            prio: jnp.ndarray, assign: jnp.ndarray, objective: str,
+            machine_rule: str, sweeps: int, frozen: jnp.ndarray | None,
+            table: jnp.ndarray | None) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Candidate -> ``(start, assign)``: SGS, then the timing sweep unless
+    the objective is makespan or ``sweeps`` is 0."""
+    dec = sgs(inst, prio, assign, machine_rule=machine_rule)
+    start = dec.start
+    if objective != "makespan" and sweeps > 0:
+        start = timing_sweep(inst, start, dec.assign, cum, deadline, sweeps,
+                             frozen=frozen, table=table)
+    return start, dec.assign
+
+
+def _combine(objective: str, carbon: jnp.ndarray, energy: jnp.ndarray,
+             pen: jnp.ndarray) -> jnp.ndarray:
+    """The fitness of a carbon or energy objective: its value (energy with
+    carbon as the paper's tie-break) plus the infeasibility penalty."""
+    if objective == "carbon":
+        return carbon + pen
+    if objective == "energy":
+        return energy + ENERGY_CARBON_TIEBREAK * carbon + pen
+    raise ValueError(f"unknown objective {objective!r}")
+
+
 @functools.partial(jax.jit,
                    static_argnames=("objective", "machine_rule", "sweeps"))
 def decode_full(inst: PackedInstance, cum: jnp.ndarray, deadline: jnp.ndarray,
@@ -67,16 +92,12 @@ def decode_full(inst: PackedInstance, cum: jnp.ndarray, deadline: jnp.ndarray,
     :mod:`repro.core.solvers.rolling`).  ``table`` is the instance's
     :func:`repro.core.decoder.sweep_table`, built here when not given.
     """
-    dec = sgs(inst, prio, assign, machine_rule=machine_rule)
-    start = dec.start
-    if objective != "makespan" and sweeps > 0:
-        start = timing_sweep(inst, start, dec.assign, cum, deadline, sweeps,
-                             frozen=frozen, table=table)
+    start, assign = _decode(inst, cum, deadline, prio, assign, objective,
+                            machine_rule, sweeps, frozen, table)
     with scope("objectives"):
-        obj: Objectives = evaluate(inst, start, dec.assign, cum)
-        return ScheduleResult(start, dec.assign, obj.makespan, obj.energy,
-                              obj.carbon,
-                              utilization(inst, start, dec.assign))
+        obj: Objectives = evaluate(inst, start, assign, cum)
+        return ScheduleResult(start, assign, obj.makespan, obj.energy,
+                              obj.carbon, utilization(inst, start, assign))
 
 
 def fitness_of(inst: PackedInstance, res: ScheduleResult,
@@ -93,11 +114,7 @@ def fitness_of(inst: PackedInstance, res: ScheduleResult,
             return res.makespan.astype(jnp.float32)
         pen = VIOLATION_PENALTY * total_violations(
             inst, res.start, res.assign, deadline).astype(jnp.float32)
-        if objective == "carbon":
-            return res.carbon + pen
-        if objective == "energy":
-            return res.energy + ENERGY_CARBON_TIEBREAK * res.carbon + pen
-        raise ValueError(f"unknown objective {objective!r}")
+        return _combine(objective, res.carbon, res.energy, pen)
 
 
 @functools.partial(jax.jit,
@@ -122,21 +139,22 @@ def population_fitness(inst: PackedInstance, cum: jnp.ndarray,
                        table: jnp.ndarray | None = None) -> jnp.ndarray:
     """Fitness of a whole candidate population.  prio/assign [Pop, T] -> [Pop].
 
-    The SA/GA hot loop: every proposal evaluation, init evaluation and
-    migration re-evaluation goes through here.  Two paths, **bit-exact
-    equal** (the contract ``tests/test_kernels.py`` property-tests):
+    The SA hot loop: every proposal evaluation, init evaluation and
+    migration re-evaluation goes through here.  One fork, chosen by
+    :func:`repro.kernels.ops.kernels_enabled` (``use_kernels`` first, then
+    ``REPRO_KERNELS``, then the platform: on a TPU by default):
 
-    * jnp path — literally ``vmap(fitness_fn)``, the golden-locked
-      reference;
-    * kernel path (``use_kernels`` / ``REPRO_KERNELS``, resolved by
-      :func:`repro.kernels.ops.kernels_enabled`) — decode (SGS + timing
-      sweep) stays vmapped jnp, but the carbon trace integral runs once
-      for the whole population in the Pallas kernel
+    * jnp path — ``vmap(fitness_fn)``, the golden-locked reference;
+    * kernel path — the same decode (:func:`_decode`) and the same
+      combination of objectives (:func:`_combine`), but the carbon trace
+      integral runs once for the whole population in the Pallas kernel
       (:func:`repro.kernels.ops.population_carbon`) instead of Pop
       separate gather chains.
 
-    The makespan objective never touches the trace, so it always takes
-    the jnp path.  Both paths sweep with one ``table``
+    The two are meant to be bit-exact equal (``tests/test_kernels.py``
+    property-tests it); on jax 0.9 the carbon can differ by one ulp.  The
+    makespan objective never touches the trace, so it always takes the
+    jnp path.  Both paths sweep with one ``table``
     (:func:`repro.core.decoder.sweep_table`), which solvers build once
     per solve.  Meant to be called from inside the solvers' jitted
     scope with ``use_kernels`` static (the branch resolves at trace time;
@@ -146,26 +164,16 @@ def population_fitness(inst: PackedInstance, cum: jnp.ndarray,
     if objective != "makespan" and sweeps > 0 and table is None:
         table = sweep_table(inst, cum)
     if objective != "makespan" and ops.kernels_enabled(use_kernels):
-        def _decode(p, a):
-            dec = sgs(inst, p, a, machine_rule=machine_rule)
-            start = dec.start
-            if sweeps > 0:
-                start = timing_sweep(inst, start, dec.assign, cum, deadline,
-                                     sweeps, frozen=frozen, table=table)
-            return start, dec.assign
-
-        starts, assigns = jax.vmap(_decode)(prio, assign)
+        starts, assigns = jax.vmap(lambda p, a: _decode(
+            inst, cum, deadline, p, a, objective, machine_rule, sweeps,
+            frozen, table))(prio, assign)
         with scope("objectives"):
             carb = ops.population_carbon(inst, starts, assigns, cum)
             pen = VIOLATION_PENALTY * jax.vmap(
                 lambda s, a: total_violations(inst, s, a, deadline)
             )(starts, assigns).astype(jnp.float32)
-            if objective == "carbon":
-                return carb + pen
-            if objective == "energy":
-                en = jax.vmap(lambda a: energy(inst, a))(assigns)
-                return en + ENERGY_CARBON_TIEBREAK * carb + pen
-            raise ValueError(f"unknown objective {objective!r}")
+            en = jax.vmap(lambda a: energy(inst, a))(assigns)
+            return _combine(objective, carb, en, pen)
     return jax.vmap(lambda p, a: fitness_fn(
         inst, cum, deadline, p, a, objective, machine_rule, sweeps,
         frozen=frozen, table=table))(prio, assign)

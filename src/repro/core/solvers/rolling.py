@@ -25,7 +25,7 @@ projection*, so the stock SGS/SA machinery is reused unchanged:
   start at the executed start), free tasks get ``arrival = max(arrival,
   r_k)`` (nothing can start in the past);
 * ``allowed``: frozen tasks shrink to the one machine they run on, so every
-  mutation/crossover in SA/GA keeps them there;
+  SA proposal keeps them there;
 * priorities: frozen tasks are projected into a high band
   (``FROZEN_BAND - start``) so SGS places them first, in executed-start
   order.  Earliest-feasible placement then reproduces the executed prefix

@@ -13,8 +13,8 @@ program computes, and costs nothing at run time.
 ``sweep_table``    the per-instance start-cost table (TPU only)
 ``objectives``     a decoded schedule's objectives and violation penalty,
                    the ``schedule_eval`` kernel included
-``search``         the SA / GA search around them: init, RNG, proposals,
-                   acceptance, best tracking, migration, selection
+``search``         the SA search around them: init, RNG, proposals,
+                   acceptance, best tracking, migration
 ``phase1``         the makespan phase and its decode
 ``phase2``         the carbon (or energy) phase, its table, final decodes
                    and fallback

@@ -8,10 +8,9 @@ lane; finished lanes (EOS or ``max_new``) are evicted and refilled —
 vLLM-style continuous batching reduced to its JAX-native core.
 
 Greedy and temperature sampling; per-request token logs; deterministic
-given the seed.  The engine is what ``examples/serve_lm.py`` and the
-offline-inference cluster workload drive.  Lane occupancy lives in the
-shared :class:`repro.serve.lanes.LanePool` — the same insert/step/evict
-shape the streaming dispatch engine (:mod:`repro.stream`) reuses for
+given the seed.  Lane occupancy lives in the shared
+:class:`repro.serve.lanes.LanePool` — the same insert/step/evict shape
+the streaming dispatch engine (:mod:`repro.stream`) reuses for
 scheduling instead of decoding.
 
 Semantics contracts (regression-locked in ``tests/test_serve.py``):

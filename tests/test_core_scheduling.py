@@ -24,11 +24,10 @@ from repro.core.instance import DAG_SHAPES, Job, Instance
 from repro.core.objectives import (carbon, energy, evaluate, makespan,
                                    utilization)
 from repro.core.validate import check_feasible_np, total_violations as violations
-from repro.core.solvers import solve_bilevel, solve_ga, solve_sa
+from repro.core.solvers import solve_bilevel, solve_sa
 from repro.core.solvers.annealing import SAConfig
 from repro.core.solvers.common import decode_full
 from repro.core.solvers.exact import exact_carbon, exact_makespan
-from repro.core.solvers.genetic import GAConfig
 
 
 def _trace_cum(rng, horizon=600, region="AU-SA"):
@@ -379,19 +378,18 @@ def test_violations_detects_each_constraint():
 # Solvers vs. the exact oracle (the CP-SAT stand-in).
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("solver", ["sa", "ga"])
-def test_solver_reaches_exact_makespan(solver, rng):
+@pytest.mark.parametrize("heterogeneous", [True, False],
+                         ids=["heterogeneous", "homogeneous"])
+def test_solver_reaches_exact_makespan(heterogeneous, rng):
     inst = generate_instance(np.random.default_rng(7), n_jobs=2, k_tasks=2,
-                             n_machines=2, heterogeneous=True,
+                             n_machines=2, heterogeneous=heterogeneous,
                              arrival_horizon=1)
     p = pack(inst)
     opt = exact_makespan(p)
     cum = _trace_cum(np.random.default_rng(7))
-    fn = solve_sa if solver == "sa" else solve_ga
-    cfgs = dict(sa=SAConfig(pop=64, iters=120), ga=GAConfig(pop=64, gens=80))
-    out = fn(p, cum, jnp.int32(1 << 27), jax.random.key(1),
-             objective="makespan", machine_rule="earliest_finish",
-             cfg=cfgs[solver])
+    out = solve_sa(p, cum, jnp.int32(1 << 27), jax.random.key(1),
+                   objective="makespan", machine_rule="earliest_finish",
+                   cfg=SAConfig(pop=64, iters=120))
     res = decode_full(p, cum, jnp.int32(1 << 27), out.prio, out.assign,
                       objective="makespan",
                       machine_rule="earliest_finish", sweeps=0)

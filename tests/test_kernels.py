@@ -45,7 +45,6 @@ from repro.core.objectives import carbon, task_durations
 from repro.core.solvers import common
 from repro.core.solvers.annealing import SAConfig, solve_sa
 from repro.core.solvers.bilevel import solve_bilevel
-from repro.core.solvers.genetic import GAConfig, solve_ga
 from repro.core.solvers.online_jax import (dirty_mask, quantile_threshold,
                                            sorted_windows)
 from repro.kernels import ops
@@ -267,7 +266,7 @@ def test_dirty_mask_property(seed, theta, window):
 
 
 # ---------------------------------------------------------------------------
-# population_fitness — the wired SA/GA hot loop, kernel == jnp
+# population_fitness — the wired SA hot loop, kernel == jnp
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("objective", ["carbon", "energy"])
@@ -343,19 +342,10 @@ def test_solve_sa_identical_under_kernels():
         _exact(r, g, f"solve_sa.{name}")
 
 
-def test_solve_ga_identical_under_kernels():
-    p, w = scenario_case(19, family="tpch", fleet="homog", horizon=400)
-    cum = jnp.asarray(w.cumulative())
-    key = jax.random.PRNGKey(2)
-    cfg = GAConfig(pop=12, gens=6)
-    ref = solve_ga(p, cum, jnp.int32(200), key, cfg=cfg, use_kernels=False)
-    got = solve_ga(p, cum, jnp.int32(200), key, cfg=cfg, use_kernels=True)
-    for r, g, name in zip(ref, got, ref._fields):
-        _exact(r, g, f"solve_ga.{name}")
-
-
-def test_solve_bilevel_batch_identical_under_kernels():
-    """The batch entry point (vmapped solve_bilevel — Pallas under vmap)."""
+@pytest.mark.parametrize("objective", ["carbon", "energy"])
+def test_solve_bilevel_batch_identical_under_kernels(objective):
+    """The batch entry point (vmapped solve_bilevel — Pallas under vmap),
+    under both objectives the kernel path combines."""
     from repro.core.solvers.bilevel import solve_bilevel_batch
     pt, pm = 32, 4
     p1, w1 = scenario_case(23, family="fanout", fleet="homog", horizon=400,
@@ -366,12 +356,12 @@ def test_solve_bilevel_batch_identical_under_kernels():
     cums = jnp.stack([jnp.asarray(w1.cumulative()),
                       jnp.asarray(w2.cumulative())])
     keys = jax.random.split(jax.random.PRNGKey(3), 2)
-    ref = solve_bilevel_batch(batch, cums, keys, stretch=1.5, cfg1=_SA_CFG,
-                              use_kernels=False)
-    got = solve_bilevel_batch(batch, cums, keys, stretch=1.5, cfg1=_SA_CFG,
-                              use_kernels=True)
+    ref = solve_bilevel_batch(batch, cums, keys, objective=objective,
+                              stretch=1.5, cfg1=_SA_CFG, use_kernels=False)
+    got = solve_bilevel_batch(batch, cums, keys, objective=objective,
+                              stretch=1.5, cfg1=_SA_CFG, use_kernels=True)
     for r, g in zip(jax.tree.leaves(ref), jax.tree.leaves(got)):
-        _exact(r, g, "solve_bilevel_batch")
+        _exact(r, g, f"solve_bilevel_batch.{objective}")
 
 
 # ---------------------------------------------------------------------------
